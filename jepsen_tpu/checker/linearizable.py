@@ -2,8 +2,8 @@
 (checker.clj:202-233), dispatching to the TPU frontier search or the CPU
 reference by :algorithm:
 
-  "wgl-tpu"     device beam search (ops/wgl.py); CPU fallback on unknown
-                when the history is small enough to afford it
+  "wgl-tpu"     device beam search (ops/wgl.py); the exact CPU engine
+                settles an unknown
   "wgl"         exact CPU search over packed ops
   "competition" device first, exact CPU to settle unknowns (mirrors
                 knossos.competition racing its solvers)
@@ -264,42 +264,19 @@ class Linearizable(Checker):
                 except Exception as e2:  # noqa: BLE001
                     if not degrade.is_resource_error(e2):
                         raise
-                    res = None
-                    # Chip-recovery rung: a halved retry that ALSO blew
-                    # up suggests a wedged chip, not a too-big program.
-                    # Clear the stale libtpu lockfile and re-probe once
-                    # per process before surrendering the device.
-                    if degrade.try_chip_reset(e2):
-                        try:
-                            res = _device(
-                                max(self.beam // 2, 64),
-                                max(self.max_beam // 2, 64),
-                                max(self.block // 2, 32),
-                                _budget_now(),
-                            )
-                        except Exception as e3:  # noqa: BLE001
-                            if not degrade.is_resource_error(e3):
-                                raise
-                            res = None
-                    if res is None:
-                        degrade.record("dispatch", "fall-through", e2)
-                        res, engine = self._cpu_exact(
-                            packed, pm, time_limit_s=_budget_now()
-                            if self.time_limit_s is not None
-                            else DEFAULT_SETTLE_BUDGET_S,
-                        )
-                        return self._render(
-                            res, packed, f"{engine}-degraded", model, pm,
-                            opts=opts,
-                        )
-            elif isinstance(e, RuntimeError) and "backend" in str(e).lower():
-                # No usable accelerator (backend init failure): the CPU
-                # search still settles the verdict rather than letting
-                # check-safe degrade it to unknown.
-                res, engine = self._cpu_exact(packed, pm)
-                return self._render(res, packed, f"{engine}-nobackend",
-                                    model, pm, opts=opts)
+                    degrade.record("dispatch", "fall-through", e2)
+                    res, engine = self._cpu_exact(
+                        packed, pm, time_limit_s=_budget_now()
+                        if self.time_limit_s is not None
+                        else DEFAULT_SETTLE_BUDGET_S,
+                    )
+                    return self._render(
+                        res, packed, f"{engine}-degraded", model, pm,
+                        opts=opts,
+                    )
             else:
+                # Anything else — a missing backend included — is an
+                # error, not a reason to run the search elsewhere.
                 raise
         used = "wgl-tpu"
         if res.valid is False and not res.final_configs:

@@ -467,7 +467,12 @@ def serve(
     queue_path: Optional[str] = None,
     tenant_weights: Optional[dict] = None,
 ) -> None:
-    """Blocking entrypoint for `jepsen checkerd`."""
+    """Blocking entrypoint for `jepsen checkerd`.  The daemon owns the
+    device: it initializes JAX's backend before it listens, so a chip
+    held by another process fails the start, not the first request."""
+    from ..ops import degrade
+
+    log.info("checkerd backend: chip %s", degrade.note_backend())
     srv = make_server(
         host, port,
         batch_window_s=batch_window_s, max_budget_s=max_budget_s,
@@ -573,6 +578,9 @@ def main(argv: Optional[list[str]] = None) -> int:
         import jax
 
         jax.config.update("jax_platforms", opts.platform)
+    from .. import compile_cache
+
+    compile_cache.place()
     serve(
         opts.host, opts.port,
         batch_window_s=opts.batch_window, max_budget_s=opts.max_budget,

@@ -224,24 +224,24 @@ def _queue_packed(initial, capacity: int, *, fifo: bool):
     def jax_step_rows_unordered(states, f, a0, a1):
         # Sort-free lane-major multiset step: enqueue fills the first
         # zero row, dequeue clears the first row matching a0 — both
-        # picked with a cumulative-count mask instead of argmin/argmax
-        # gathers.  The resulting state is NOT kept sorted; that is
+        # picked as the least masked row index (a min-reduction; Mosaic
+        # lowers neither cumsum nor argmin/argmax gathers).  The resulting state is NOT kept sorted; that is
         # sound because enqueue/dequeue legality is order-independent
         # and canonical (sorted) form is only needed for the heavy
         # rounds' state dedup — whose inputs are jax_step outputs,
         # which re-sort unconditionally.  Unsorted states therefore
         # only pass through the sweep, never reach a dedup compare.
+        import jax
         import jax.numpy as jnp
 
         is_enq = f == F_ENQ
+        row = jax.lax.broadcasted_iota(jnp.int32, states.shape, 0)
         zero_i = (states == 0).astype(jnp.int32)
-        first_zero = (jnp.cumsum(zero_i, axis=0) == 1) & (states == 0)
+        first_zero = row == jnp.where(states == 0, row, C).min(axis=0)
         has_room = zero_i.max(axis=0)                     # (B,) 0/1
         enq = jnp.where(first_zero, a0, states)
         match_i = (states == a0).astype(jnp.int32)
-        first_match = (jnp.cumsum(match_i, axis=0) == 1) & (
-            states == a0
-        )
+        first_match = row == jnp.where(states == a0, row, C).min(axis=0)
         present = match_i.max(axis=0)                     # (B,) 0/1
         deq = jnp.where(first_match, 0, states)
         legal = jnp.where(is_enq, has_room, present)

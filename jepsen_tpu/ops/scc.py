@@ -29,6 +29,7 @@ from typing import Any, Optional, Sequence
 import numpy as np
 
 from ..checker.elle.graph import DepGraph, check_cycles
+from .. import telemetry
 from ..telemetry import roofline
 
 _kernel_cache: dict[tuple, Any] = {}
@@ -95,15 +96,11 @@ def _get_kernel(K: int, V: int, mesh=None):
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.mesh import shard_map_compat
-
-        shard_map, rep_kw = shard_map_compat()
-
         fn = roofline.instrument(jax.jit(
-            shard_map(
+            jax.shard_map(
                 has_cycle, mesh=mesh,
                 in_specs=P("keys"), out_specs=P("keys"),
-                **rep_kw,
+                check_vma=False,
             )
         ))
     else:
@@ -128,6 +125,7 @@ def screen_cycles(
         K = ((n + shards - 1) // shards) * shards
     adj, _ = pack_adjacency(graphs, pad_keys_to=K)
     flags = np.asarray(_get_kernel(K, adj.shape[1], mesh)(jnp.asarray(adj)))
+    telemetry.count("wgl.scc.screened-graphs", n)
     return flags[:n]
 
 

@@ -321,10 +321,6 @@ def _make_block_fn_sharded(B: int, W: int, SW: int, Cmax: int, jax_step,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
-
-    shard_map, rep_kw = shard_map_compat()
-
     axis = mesh.axis_names[0]
     n = mesh.devices.size
     assert B % n == 0 and Cmax % n == 0, (B, Cmax, n)
@@ -402,11 +398,11 @@ def _make_block_fn_sharded(B: int, W: int, SW: int, Cmax: int, jax_step,
 
     pb = P(axis)
     pr = P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         block_local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), pb, pr) + (pr,) * 12,
         out_specs=(P(axis, None), P(axis, None), pb, pr, pr, pr, pr),
-        **rep_kw,
+        check_vma=False,
     )
     return roofline.instrument(jax.jit(sharded))
 

@@ -283,16 +283,12 @@ def _get_kernel(B: int, N: int, SW: int, Cmax: int, jax_step, mesh=None,
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.mesh import shard_map_compat
-
-        shard_map, rep_kw = shard_map_compat()
-
         pk = P("keys")
         in_specs = (pk, pk, pk, pk, pk, pk, P(None), pk)
         out_specs = (pk, pk, pk, pk)
-        batched = shard_map(
+        batched = jax.shard_map(
             batched, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **rep_kw,
+            check_vma=False,
         )
     fn = roofline.instrument(jax.jit(batched))
     _kernel_cache[key] = fn

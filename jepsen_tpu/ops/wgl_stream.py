@@ -83,6 +83,7 @@ def stream_model(pm: PackedModel) -> PackedModel:
     if cached is not None:
         return cached
 
+    import jax
     import jax.numpy as jnp
 
     init = tuple(int(v) for v in pm.init_state)
@@ -106,13 +107,18 @@ def stream_model(pm: PackedModel) -> PackedModel:
         def jax_step_rows(states, f, a0, a1):
             # Lane-major (SW, B); scatter-free (jnp.where only), so the
             # wrap stays Mosaic-safe for the Pallas sweep.
+            # The init column is built from scalar literals: a Pallas
+            # kernel may not capture array constants.
             is_reset = f == F_RESET
             ns, legal = base_rows(states, jnp.where(is_reset, 0, f),
                                   a0, a1)
-            init_col = jnp.asarray(init, jnp.int32)[:, None]
+            row = jax.lax.broadcasted_iota(jnp.int32, ns.shape, 0)
+            init_col = jnp.zeros(ns.shape, jnp.int32)
+            for i, v in enumerate(init):
+                init_col = jnp.where(row == i, v, init_col)
             return (
                 jnp.where(is_reset, init_col, ns),
-                jnp.where(is_reset, jnp.ones_like(legal), legal),
+                jnp.logical_or(legal, is_reset).astype(legal.dtype),
             )
 
     def py_step(s, f, a0, a1):

@@ -493,19 +493,12 @@ class IndependentChecker(Checker):
                        - (_time.monotonic() - t_tiers))
 
         results_stream: dict[Any, dict] = {}
-        try:
-            stream_v = check_wgl_witness_stream(
-                [all_packs[k] for k in keys], pm,
-                time_limit_s=lin.time_limit_s,
-            )
-        except Exception:  # noqa: BLE001 — sound fallback exists
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "stream witness failed; falling back to the batched "
-                "search for all keys", exc_info=True,
-            )
-            stream_v = [None] * len(keys)
+        # Resource errors degrade inside the stream tier; anything
+        # else is a bug and propagates.
+        stream_v = check_wgl_witness_stream(
+            [all_packs[k] for k in keys], pm,
+            time_limit_s=lin.time_limit_s,
+        )
         for k, v in zip(keys, stream_v):
             if v is True:
                 results_stream[k] = {

@@ -79,22 +79,6 @@ def multihost_init(coordinator: str, num_processes: int,
     )
 
 
-def shard_map_compat():
-    """(shard_map, replication-check kwargs) across jax versions: the
-    stable `jax.shard_map` (>= 0.8) renamed check_rep -> check_vma.
-    Checking is disabled either way — checker outputs are fully
-    sharded or psum-replicated by construction.  Single shim for the
-    three shard_map call sites (wgl, wgl_batched, scc)."""
-    try:
-        from jax import shard_map  # jax >= 0.8
-
-        return shard_map, {"check_vma": False}
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map, {"check_rep": False}
-
-
 def checker_mesh(test: Optional[dict] = None):
     """The mesh a checker should use: the test map's "mesh" entry if set,
     else all local devices, else None for single-device."""

@@ -1,6 +1,6 @@
 """Persistent plan memo + XLA compile cache.
 
-Two layers, both rooted in one directory (`JEPSEN_PLAN_CACHE=<dir>` or
+Two layers, activated by one directory (`JEPSEN_PLAN_CACHE=<dir>` or
 `checkerd --plan-cache <dir>`; no directory = no on-disk state, the
 in-memory settle memo behaves exactly as before):
 
@@ -12,9 +12,10 @@ in-memory settle memo behaves exactly as before):
   daemon re-checking the same history skips the whole settle ladder.
   Crash safety comes free from BlockWriter's torn-tail truncation.
 
-* **XLA compile cache** — JAX's on-disk compilation cache pointed at
-  `<dir>/xla/`, so the second process pays no tracing/lowering for the
-  kernels the first one compiled.
+* **XLA compile cache** — JAX's on-disk compilation cache, kept where
+  `compile_cache.place()` puts it (`JAX_COMPILATION_CACHE_DIR`, else
+  `<repo>/.jax_cache`) and never under `<dir>`; activation only drops
+  the size and compile-time thresholds so every kernel is cached.
 
 Only *decisive, sanitized* verdicts may be journaled: callers strip
 positional certificates (final-configs, crashed-op, counterexample
@@ -37,7 +38,6 @@ from ..store import format as fmt
 log = logging.getLogger(__name__)
 
 MEMO_FILE = "plan-memo.jtpu"
-XLA_SUBDIR = "xla"
 
 #: Journal entries larger than this are not memoized — a plan memo is a
 #: verdict cache, not a certificate store.
@@ -157,7 +157,8 @@ _lock = threading.Lock()
 _memo: Optional[PlanMemo] = None
 _dir: Optional[str] = None
 _configured = False
-_xla_enabled = False
+#: The compile-cache directory once enable_xla_cache() ran.
+_xla_dir: Optional[str] = None
 
 
 def configure(cache_dir: Optional[str]) -> None:
@@ -172,7 +173,7 @@ def configure(cache_dir: Optional[str]) -> None:
         _dir = cache_dir
         _configured = True
     if cache_dir:
-        enable_xla_cache(cache_dir)
+        enable_xla_cache()
 
 
 def cache_dir() -> Optional[str]:
@@ -190,10 +191,10 @@ def active_memo() -> Optional[PlanMemo]:
     d = cache_dir()
     if not d:
         return None
-    if not _xla_enabled:
+    if not _xla_dir:
         # Env-var activation (JEPSEN_PLAN_CACHE with no configure()
         # call) must wire the compile cache too, not just the memo.
-        enable_xla_cache(d)
+        enable_xla_cache()
     with _lock:
         if _memo is not None and _memo.path == os.path.join(d, MEMO_FILE):
             return _memo
@@ -206,36 +207,34 @@ def active_memo() -> Optional[PlanMemo]:
         return _memo
 
 
-def enable_xla_cache(cache_dir_: str) -> Optional[str]:
-    """Wires JAX's persistent compilation cache under the plan cache
-    dir.  Idempotent; thresholds zeroed so even the sub-second CPU
-    kernels of the test suite land in it (the smoke tool counts files
-    here to assert compile-cache warm start)."""
-    global _xla_enabled
-    xdir = os.path.join(cache_dir_, XLA_SUBDIR)
+def enable_xla_cache() -> Optional[str]:
+    """Turns JAX's persistent compilation cache on for every kernel
+    (thresholds zeroed, so even the sub-second CPU kernels of the test
+    suite land in it) in the directory `compile_cache.place()` picks.
+    Idempotent; returns that directory."""
+    global _xla_dir
     try:
-        os.makedirs(xdir, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", xdir)
+        from .. import compile_cache
+
+        xdir = compile_cache.place()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _xla_enabled = True
+        _xla_dir = xdir
         return xdir
-    except Exception as e:  # jax missing/old: plan memo still works
+    except Exception as e:  # jax missing: plan memo still works
         log.warning("XLA persistent cache unavailable: %r", e)
         return None
 
 
-def xla_cache_files(cache_dir_: Optional[str] = None) -> int:
+def xla_cache_files() -> int:
     """How many compiled executables the XLA cache holds — the smoke
     tool's 'no new compilations on run 2' probe."""
-    d = cache_dir_ or cache_dir()
-    if not d:
+    if not _xla_dir:
         return 0
-    xdir = os.path.join(d, XLA_SUBDIR)
     try:
-        return sum(1 for n in os.listdir(xdir)
+        return sum(1 for n in os.listdir(_xla_dir)
                    if not n.startswith("."))
     except OSError:
         return 0
@@ -248,8 +247,8 @@ def stats() -> dict:
     return {
         "dir": d,
         "memo": m.stats() if m else None,
-        "xla_files": xla_cache_files(d) if d else 0,
-        "xla_enabled": _xla_enabled,
+        "xla_files": xla_cache_files(),
+        "xla_enabled": _xla_dir is not None,
     }
 
 

@@ -217,17 +217,12 @@ def _run_stream(ctx: ExecContext, node: PassNode, keys: list):
         kw["max_restarts"] = node.knobs["max_restarts"]
     limit = (ctx.lin.time_limit_s if ctx.mode == "cohort"
              else ctx.budget_left())
-    try:
-        stream_v = check_wgl_witness_stream(
-            [ctx.packs[k] for k in keys], ctx.pm,
-            time_limit_s=limit, **kw,
-        )
-    except Exception:  # noqa: BLE001 — sound fallback exists
-        log.warning(
-            "stream witness failed; falling back to the batched "
-            "search for all keys", exc_info=True,
-        )
-        stream_v = [None] * len(keys)
+    # Device resource errors degrade inside the stream tier (recorded
+    # as wgl.degrade.stream.*); anything else is a bug and propagates.
+    stream_v = check_wgl_witness_stream(
+        [ctx.packs[k] for k in keys], ctx.pm,
+        time_limit_s=limit, **kw,
+    )
     decided: dict = {}
     rest = []
     for k, v in zip(keys, stream_v):

@@ -56,30 +56,3 @@ def test_bench_emits_one_json_line_cpu():
     assert scale["valid"] is True
     assert scale["ops"] >= 900_000
     assert scale["max_ops_at_300s"] > scale["ops"]
-
-
-def test_last_good_keeps_best_across_a_slow_rerun(tmp_path, monkeypatch):
-    """record_last_good: `value` tracks the most recent TPU capture
-    (driver reproducibility) but `best_*` must survive a sluggish
-    chip mood, so one slow rerun can't erase the headline."""
-    import bench
-
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH",
-                        str(tmp_path / "last_good.json"))
-
-    def line(value):
-        return json.dumps({
-            "metric": "wgl_linearizability_throughput",
-            "value": value, "unit": "ops/s", "vs_baseline": value / 1667,
-            "platform": "tpu", "elapsed_s": 1.0, "n_ops": 74614,
-        })
-
-    bench.record_last_good(line(170000.0))
-    bench.record_last_good(line(90000.0))   # sick-chip rerun
-    rec = json.load(open(tmp_path / "last_good.json"))
-    assert rec["value"] == 90000.0          # most recent, honestly
-    assert rec["best_value"] == 170000.0    # headline preserved
-    bench.record_last_good(line(200000.0))  # a better run retakes it
-    rec = json.load(open(tmp_path / "last_good.json"))
-    assert rec["value"] == 200000.0
-    assert rec["best_value"] == 200000.0

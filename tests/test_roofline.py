@@ -336,25 +336,6 @@ def test_ingest_counters_survive_scoped_reset():
 # ------------------------------------------------------- chip dossier
 
 
-def test_chip_dossier_writes_structured_json(tmp_path, monkeypatch):
-    from jepsen_tpu.ops import degrade
-
-    monkeypatch.setenv(degrade.DOSSIER_ENV, str(tmp_path))
-    path = degrade.write_chip_dossier()
-    assert path == str(tmp_path / "chip.json")
-    with open(path) as f:
-        d = json.load(f)
-    assert d["v"] == 1
-    assert "python" in d["versions"]
-    assert "jax" in d["versions"]
-    assert isinstance(d["env"], dict)
-    for k in d["env"]:
-        assert k.startswith(degrade._DOSSIER_ENV_PREFIXES)
-
-
-# ----------------------------------------------------------- perf gate
-
-
 def _store_records(tmp_path, name, factor=1.0):
     path = str(tmp_path / name)
     perf_gate._synthetic_store(path, slow_pass_factor=factor)

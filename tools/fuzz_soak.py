@@ -33,8 +33,8 @@ def main() -> int:
     ap.add_argument("--seed-base", type=int, default=0)
     ap.add_argument("--platform", default="cpu",
                     choices=("cpu", "default"),
-                    help='"cpu" pins the CPU backend (default: this '
-                         "tool usually runs beside a wedged chip)")
+                    help='"cpu" pins the CPU backend (default); '
+                         '"default" lets JAX pick the chip')
     args = ap.parse_args()
 
     # Append (don't setdefault): an ambient XLA_FLAGS must not

@@ -15,8 +15,8 @@ backend is available, in two variants:
 
 Each variant runs >= --reps measured reps (plus one compile warm-up)
 and prints ONE JSON line with median + spread (utils.summarize_times)
-and the backend platform, so tools/chip_watch.py can verify a capture
-really ran on the chip before recording it.
+and the backend platform, so a reader can tell a chip capture from a
+CPU one.
 
 Usage:
   python tools/independent_bench.py [--keys 200] [--key-ops 100]

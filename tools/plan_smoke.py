@@ -114,6 +114,9 @@ def run_worker(tag: str, tmp: str, *, plan: str,
     env = dict(os.environ)
     env["JEPSEN_PLAN"] = plan
     env.pop("JEPSEN_PLAN_CACHE", None)
+    # The compile cache lives where JAX_COMPILATION_CACHE_DIR says; a
+    # fresh one per smoke so the cold run starts empty.
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp, "xla")
     if cache is not None:
         env["JEPSEN_PLAN_CACHE"] = cache
     rc = subprocess.run(
