@@ -101,6 +101,8 @@ DECLARED_NAMESPACES = {
               "framing, daemon decode (history/, streaming/, "
               "checkerd/)",
     "checker": "checker harness (checker/)",
+    "jit": "JAX compiles and persistent-cache hits, from "
+           "jax.monitoring events (telemetry/__init__.py)",
     "checkerd": "checker daemon fleet (checkerd/)",
     "checkerd.queue": "crash-safe queue journal (checkerd/journal.py)",
     "checkerd.overload": "overload control plane: fair queue, deadline "

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+from .. import telemetry
 from ..history.core import History
 from ..telemetry import profile
 from ..history.packed import pack_history
@@ -88,7 +89,8 @@ class Linearizable(Checker):
             return self._host_fallback(history, model, "wgl-host", opts)
 
         try:
-            packed = pack_history(history, pm.encode)
+            with telemetry.span("ingest.pack"):
+                packed = pack_history(history, pm.encode)
         except ValueError:
             # The history contains ops the packed form cannot encode
             # soundly (e.g. indeterminate dequeues): host model search.
@@ -170,8 +172,6 @@ class Linearizable(Checker):
             except Exception:  # noqa: BLE001 — legacy ladder is the net
                 import logging
 
-                from .. import telemetry
-
                 telemetry.count("wgl.plan.fallback")
                 logging.getLogger(__name__).warning(
                     "plan executor failed; using the legacy ladder",
@@ -199,7 +199,8 @@ class Linearizable(Checker):
         from .refute import check_refute
 
         t_start = _time.monotonic()
-        ref = check_refute(packed, pm, time_limit_s=self.time_limit_s)
+        with telemetry.span("wgl.screen"):
+            ref = check_refute(packed, pm, time_limit_s=self.time_limit_s)
         if ref is not None:
             return self._render(ref, packed, "refute-screen", model, pm,
                                 opts=opts)

@@ -320,9 +320,10 @@ def check_wgl_witness_stream(
                 if remaining <= 0:
                     break
             end = min(K, start + span)
-            combined, override, key_of_bar = concat_packs(
-                packs[start:end]
-            )
+            with telemetry.span("wgl.stream.concat"):
+                combined, override, key_of_bar = concat_packs(
+                    packs[start:end]
+                )
             info: dict = {}
             passes += 1
             try:

@@ -442,6 +442,7 @@ def _make_pallas_sweep(B: int, W: int, SW: int, K: int, jax_step_rows,
             pl.BlockSpec((1, 1), memory_space=pltpu.SMEM),
         ),
         interpret=interpret,
+        name="wgl_sweep",
     )
 
     def sweep(start_k, bars, member, states, alive):

@@ -230,7 +230,8 @@ class IndependentChecker(Checker):
         self.streaming = streaming
 
     def check(self, test: dict, history: History, opts: dict) -> dict:
-        subs = subhistories(history)
+        with telemetry.span("ingest.split"):
+            subs = subhistories(history)
         keys = list(subs)
         if not keys:
             return {"valid": True, "results": {}, "key-count": 0}
@@ -372,20 +373,21 @@ class IndependentChecker(Checker):
 
         all_packs = {}
         unpackable = []
-        for k in keys:
-            try:
-                p = pack_history(subs[k], pm.encode)
-            except ValueError:
-                # e.g. an indeterminate dequeue: no packed form for
-                # this key — the single-key checker falls back to the
-                # host-model search itself.
-                unpackable.append(k)
-                continue
-            if pm.validate_packed is not None and \
-                    pm.validate_packed(p) is not None:
-                unpackable.append(k)
-                continue
-            all_packs[k] = p
+        with telemetry.span("ingest.pack", keys=len(keys)):
+            for k in keys:
+                try:
+                    p = pack_history(subs[k], pm.encode)
+                except ValueError:
+                    # e.g. an indeterminate dequeue: no packed form for
+                    # this key — the single-key checker falls back to
+                    # the host-model search itself.
+                    unpackable.append(k)
+                    continue
+                if pm.validate_packed is not None and \
+                        pm.validate_packed(p) is not None:
+                    unpackable.append(k)
+                    continue
+                all_packs[k] = p
 
         # Compiled-plan route (jepsen_tpu/plan/): the same ladder —
         # online consume, long-key split, stream witness, settle

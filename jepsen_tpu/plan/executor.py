@@ -618,7 +618,9 @@ def execute(plan: Plan, ctx: ExecContext,
         if not keys:
             continue
         telemetry.count("wgl.plan.pass-runs")
-        decided, routed = family(node.family).runner(ctx, node, keys)
+        fam = family(node.family)
+        with telemetry.span(fam.span):
+            decided, routed = fam.runner(ctx, node, keys)
         results.update(decided)
         route(node, routed)
 
@@ -654,7 +656,9 @@ def _execute_group(ctx: ExecContext, grp: list, gkeys: list,
             if not keys:
                 continue
             telemetry.count("wgl.plan.pass-runs")
-            decided, routed = family(node.family).runner(ctx, node, keys)
+            fam = family(node.family)
+            with telemetry.span(fam.span):
+                decided, routed = fam.runner(ctx, node, keys)
             for k, r in decided.items():
                 d = gs.key_digest[k]
                 gs.group_result[d] = r

@@ -52,8 +52,12 @@ class PassFamily:
     #: Knob names the cost model may choose for nodes of this family.
     knob_spec: tuple = ()
     doc: str = ""
+    #: The telemetry span the executor opens around each run of this
+    #: family, named once here: ``wgl.plan.pass.<name>``.
+    span: str = dataclasses.field(init=False, repr=False, compare=False)
 
     def __post_init__(self) -> None:
+        object.__setattr__(self, "span", f"wgl.plan.pass.{self.name}")
         if self.soundness not in SOUNDNESS:
             raise ValueError(
                 f"{self.name}: soundness {self.soundness!r} not in "

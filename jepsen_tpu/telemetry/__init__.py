@@ -33,6 +33,12 @@ dispatch), ``checker.<Name>`` (check_safe), ``wgl.*`` (device search:
 compile vs execute, witness tiers, stream), ``bench.*`` (bench.py
 phases).  The registry is process-wide on purpose — a run's worker
 threads, checker pools, and device callbacks all land in one trace.
+
+Once JAX is imported, an enabled span also opens a
+`jax.profiler.TraceAnnotation` of its name, so a profiler trace holds
+every program span on its host plane, on the device ops' clock; and
+``jit.*`` counters follow JAX's compile events (`jax.monitoring`).
+Telemetry never imports JAX itself.
 """
 
 from __future__ import annotations
@@ -41,6 +47,7 @@ import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -99,6 +106,62 @@ def enable(on: bool = True) -> None:
     """Programmatic override of JEPSEN_TELEMETRY (tests, embedding)."""
     global _enabled
     _enabled = bool(on)
+    if _enabled:
+        _jax_profiler()
+
+
+# ---------------------------------------------------------------------------
+# JAX: profiler annotations and compile counters
+# ---------------------------------------------------------------------------
+
+#: `jax.profiler`, set by `_jax_profiler` once telemetry is on in a
+#: process that has imported JAX; None until then.
+_profiler: Any = None
+_jax_lock = threading.Lock()
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+#: A persistent-cache hit is recorded inside the backend-compile event
+#: that wraps it, on the same thread: the flag keeps that executable
+#: out of `jit.compiles`.
+_cache_hit_open = threading.local()
+
+
+def _on_jax_event(event: str, **_kw: Any) -> None:
+    if not _enabled:
+        return
+    if event == _CACHE_HIT:
+        _cache_hit_open.hit = True
+        count("jit.cache-hits")
+
+
+def _on_jax_duration(event: str, duration: float, **_kw: Any) -> None:
+    if not _enabled or event != _BACKEND_COMPILE:
+        return
+    if getattr(_cache_hit_open, "hit", False):
+        _cache_hit_open.hit = False
+    else:
+        count("jit.compiles")
+
+
+def _jax_profiler() -> Any:
+    """`jax.profiler` when JAX is already imported, else None.  The
+    first call that finds JAX registers the ``jit.*`` listeners:
+    `jit.compiles` (executables the compiler built) and
+    `jit.cache-hits` (executables loaded from the persistent cache)."""
+    global _profiler
+    if _profiler is None and "jax" in sys.modules:
+        with _jax_lock:
+            if _profiler is None:
+                import jax.monitoring
+                import jax.profiler
+
+                jax.monitoring.register_event_listener(_on_jax_event)
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+                _profiler = jax.profiler
+    return _profiler
 
 
 def reset() -> None:
@@ -225,7 +288,7 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    __slots__ = ("name", "attrs", "_t0")
+    __slots__ = ("name", "attrs", "_t0", "_ann")
 
     def __init__(self, name: str, attrs: Optional[dict]):
         self.name = name
@@ -239,6 +302,11 @@ class Span:
             self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
+        prof = _profiler or _jax_profiler()
+        self._ann = None
+        if prof is not None:
+            self._ann = prof.TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -246,6 +314,8 @@ class Span:
         global _events_dropped
         t0 = self._t0
         dur = time.perf_counter_ns() - t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         t = threading.current_thread()
         with _lock:
             st = _span_stats.get(self.name)
