@@ -28,6 +28,8 @@ histories blow up search width (SURVEY.md §7 "hard parts").
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from itertools import chain
+from operator import attrgetter
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -197,11 +199,13 @@ class PackedBuilder:
         for o in h: b.append(o)
         packed_to_bytes(b.finish()) == packed_to_bytes(pack_history(h, encode))
 
-    The emit/pairing logic below is a line-for-line transcription of
-    pack_history's — same client filter, same dense event enumeration,
-    same FAIL/None-encode drops, same double-invoke and unfinished-op
-    indeterminates — only driven one op at a time instead of over a
-    complete list.  Keep the two in lockstep.
+    `append`/`_append_client`/`_emit` are the per-op reference: the
+    client filter, the dense event enumeration, the FAIL/None-encode
+    drops, the double-invoke and unfinished-op indeterminates, and the
+    order in which rows reach the encoder (which fixes the interner's
+    codes).  pack_history and append_many are the columnar form of the
+    same state machine (`_pair`), tested byte-for-byte against it in
+    tests/test_pack_columnar.py.
 
     Mid-run, `snapshot()` returns the STABLE ROW PREFIX: rows whose
     invocation event index is < s, where s = min invocation index over
@@ -262,7 +266,6 @@ class PackedBuilder:
 
     def _emit(self, inv_e: int, invoke_op: Op, ret_e: int,
               comp: Optional[Op]) -> None:
-        # Mirror of pack_history's emit() — keep in lockstep.
         if comp is not None and comp.type == FAIL:
             return  # certainly never happened
         status = ST_OK if (comp is not None and comp.type == OK) else ST_INFO
@@ -303,23 +306,12 @@ class PackedBuilder:
 
     def append_many(self, ops: "Any") -> None:
         """Feeds a chunk of ops in journal order — byte-identical to
-        calling append() per op (tested in tests/test_wgl_packed.py),
-        but with the invoke/completion pairing done columnar in numpy.
-
-        Correctness rests on one invariant of append()'s state machine:
-        after any client op on process p, p's pending state is simply
-        "that op was an invoke".  So on a per-process event sequence,
-        a completion pairs with its immediate predecessor iff that
-        predecessor is an invoke, an invoke becomes a double-invoke
-        indeterminate iff its successor is another invoke, and only
-        each process's FIRST op can interact with pending state carried
-        in from before the chunk (handled scalar below).  A stable sort
-        by process exposes those predecessor/successor relations as
-        shifted boolean masks.  Emit order differs from append()'s, but
-        every row has a unique inv event index and each consumer
-        (snapshot/finish/discard) sorts or reduces over inv, so the
-        serialized bytes cannot tell.
-        """
+        calling append() per op (tests/test_pack_columnar.py), with the
+        pairing done columnar (`_pair`) and the rows encoded in
+        append()'s emit order.  The invocations pending from earlier
+        appends enter the pairing as events before the chunk, in
+        pending-dict order, so a chunk's first op per process pairs
+        with or supersedes them like any other predecessor."""
         if self._finished:
             raise RuntimeError("PackedBuilder already finished")
         client = [o for o in ops if isinstance(o.process, int)]
@@ -330,106 +322,27 @@ class PackedBuilder:
             return
         e0 = self._e
         self._e = e0 + n
-        is_inv = np.array([o.type == INVOKE for o in client], dtype=bool)
-        procs = np.array([o.process for o in client], dtype=np.int64)
-        order = np.argsort(procs, kind="stable")
-        p_sorted = procs[order]
-        inv_sorted = is_inv[order]
-        same_prev = np.empty(n, dtype=bool)
-        same_prev[0] = False
-        np.equal(p_sorted[1:], p_sorted[:-1], out=same_prev[1:])
-        prev_inv = np.empty(n, dtype=bool)
-        prev_inv[0] = False
-        prev_inv[1:] = inv_sorted[:-1]
-        same_next = np.empty(n, dtype=bool)
-        same_next[:-1] = same_prev[1:]
-        same_next[-1] = False
-        next_inv = np.empty(n, dtype=bool)
-        next_inv[:-1] = inv_sorted[1:]
-        next_inv[-1] = False
-        oi = order.tolist()
-        encode = self.encode
-        emit_row = self._rows.append
-        # Chunk-boundary interactions: each process's first op vs any
-        # pending invoke carried in from earlier appends.
-        for j in np.nonzero(~same_prev)[0].tolist():
-            i = oi[j]
-            o = client[i]
-            prev = self._pending.pop(o.process, None)
-            if prev is None:
-                continue
-            if is_inv[i]:
-                # Double invoke without completion: the carried op is
-                # indeterminate (it may still chain into doubles below).
-                self._emit(prev[0], prev[1], -1, None)
-            else:
-                self._emit(prev[0], prev[1], e0 + i, o)
-        # Within-chunk pairs: a completion whose in-process predecessor
-        # is an invoke.  _emit's logic, inlined: the loop body runs once
-        # per live op and the method dispatch is measurable at ingest
-        # rates — keep in lockstep with _emit.
-        pair_j = np.nonzero(
-            (~inv_sorted) & same_prev & prev_inv
-        )[0].tolist()
-        enc_many = getattr(encode, "many", None)
-        if enc_many is not None and pair_j:
-            # Batched encode: collect the surviving (inv, comp) pairs,
-            # encode in one call (the model inlines its interner), then
-            # build rows.  Same drops, same codes as the scalar branch.
-            meta = []
-            items = []
-            for j in pair_j:
-                ic = oi[j]
-                comp = client[ic]
-                t = comp.type
-                if t == FAIL:
-                    continue  # certainly never happened
-                ii = oi[j - 1]
-                meta.append((ii, ic, t))
-                items.append((client[ii], comp))
-            for (ii, ic, t), enc in zip(meta, enc_many(items)):
-                if enc is None:
-                    continue
-                fc, a0, a1 = enc
-                inv_op = client[ii]
-                if t == OK:
-                    emit_row((e0 + ii, e0 + ic, inv_op.process, ST_OK,
-                              fc, a0, a1, inv_op.index))
-                else:
-                    emit_row((e0 + ii, NO_RET, inv_op.process, ST_INFO,
-                              fc, a0, a1, inv_op.index))
-        else:
-            for j in pair_j:
-                ii = oi[j - 1]
-                inv_op = client[ii]
-                comp = client[oi[j]]
-                t = comp.type
-                if t == FAIL:
-                    continue  # certainly never happened
-                enc = encode(inv_op, comp)
-                if enc is None:
-                    continue
-                fc, a0, a1 = enc
-                if t == OK:
-                    emit_row((e0 + ii, e0 + oi[j], inv_op.process, ST_OK,
-                              fc, a0, a1, inv_op.index))
-                else:
-                    emit_row((e0 + ii, NO_RET, inv_op.process, ST_INFO,
-                              fc, a0, a1, inv_op.index))
-        # Within-chunk double invokes: superseded by the next invoke.
-        for j in np.nonzero(inv_sorted & same_next & next_inv)[0].tolist():
-            i = oi[j]
-            inv_op = client[i]
-            enc = encode(inv_op, None)
-            if enc is None:
-                continue
-            fc, a0, a1 = enc
-            emit_row((e0 + i, NO_RET, inv_op.process, ST_INFO,
-                      fc, a0, a1, inv_op.index))
-        # Trailing invokes become the new pending state.
-        for j in np.nonzero(inv_sorted & ~same_next)[0].tolist():
-            i = oi[j]
-            self._pending[client[i].process] = (e0 + i, client[i])
+        pending = self._pending
+        carried = list(pending.values())
+        k = len(carried)
+        run = [op for _, op in carried] + client
+        events = np.concatenate([
+            np.array([e for e, _ in carried], dtype=np.int64),
+            np.arange(e0, e0 + n, dtype=np.int64),
+        ])
+        types, procs = _client_columns(run)
+        inv, comp, tail, tail_start = _pair(types, procs)
+        rows = _encode_rows(self.encode, run, types, procs, events, inv, comp)
+        self._rows.extend(map(tuple, rows.tolist()))
+        # A carried invocation stays where it is in the pending dict
+        # only while its process has no completion in the chunk; the
+        # rest enter in the order append() would insert them.
+        stay = set(tail_start.tolist())
+        for c in range(k):
+            if c not in stay:
+                del pending[run[c].process]
+        for t in tail.tolist():
+            pending[run[t].process] = (int(events[t]), run[t])
 
     def _append_client(self, o: Op) -> None:
         """append() minus the client filter (caller already checked)."""
@@ -613,14 +526,18 @@ class PackedBuilder:
         self._finished = True
         self._flush_ingest()
         with telemetry.span("ingest.finish", rows=self.n_rows):
-            # Unfinished invocations are indeterminate (pending dict
-            # order, matching pack_history's final loop).
-            for inv_e, inv_op in self._pending.values():
-                self._emit(inv_e, inv_op, -1, None)
-            self._pending.clear()
-            rows = self._stable + self._rows
-            rows.sort(key=lambda r: r[0])
-            return _rows_to_packed(rows, with_preds=True)
+            return self._close()
+
+    def _close(self) -> "PackedOps":
+        """finish() without its telemetry (pack_history's per-op path)."""
+        # Unfinished invocations are indeterminate, in pending dict
+        # order.
+        for inv_e, inv_op in self._pending.values():
+            self._emit(inv_e, inv_op, -1, None)
+        self._pending.clear()
+        rows = self._stable + self._rows
+        rows.sort(key=lambda r: r[0])
+        return _rows_to_packed(rows, with_preds=True)
 
 
 def _require_i32(arr: "np.ndarray") -> None:
@@ -643,22 +560,33 @@ def _require_i32(arr: "np.ndarray") -> None:
 
 
 def _rows_to_packed(rows: list, *, with_preds: bool) -> "PackedOps":
-    """Shared row-tuples -> PackedOps tail of pack_history.  `rows`
-    must already be inv-sorted.  with_preds=False leaves preds/horizon
-    zero for witness-only snapshots."""
+    """The builder's inv-sorted row tuples -> PackedOps."""
     if rows:
         arr = np.array(rows, dtype=np.int64)
     else:
         arr = np.zeros((0, 8), dtype=np.int64)
+    return _packed(arr, with_preds=with_preds)
 
+
+def _packed(arr: "np.ndarray", *, with_preds: bool) -> "PackedOps":
+    """Inv-sorted (n, 8) int64 rows (inv, ret, process, status, f, a0,
+    a1, src_index) -> PackedOps.  with_preds=False leaves preds/horizon
+    zero for witness-only snapshots."""
     inv = arr[:, 0]
     ret = arr[:, 1]
     n = arr.shape[0]
     _require_i32(arr)
 
     if with_preds:
+        # preds[a] = #{y != a : ret(y) < inv(a)}
+        # horizon[a] = #{y != a : inv(y) < ret(a)}
+        # O(n log n) via sorted ret values.
         ret_sorted = np.sort(ret)
         preds = np.searchsorted(ret_sorted, inv, side="left").astype(np.int64)
+        # inv is sorted ascending already; count invs strictly below
+        # each ret.  Subtract self when inv(a) < ret(a) (always true
+        # for completed ops; for NO_RET ops every other op counts, self
+        # too — subtract 1).
         inv_before_ret = np.searchsorted(inv, ret, side="left").astype(np.int64)
         horizon = inv_before_ret - 1
         horizon = np.minimum(horizon, n - 1)
@@ -680,6 +608,129 @@ def _rows_to_packed(rows: list, *, with_preds: bool) -> "PackedOps":
     )
 
 
+# -- the columnar pass --------------------------------------------------------
+
+#: Type codes of the columnar pass.  A completion that is neither :ok
+#: nor :fail reads as :info, as the per-op path reads it.
+_INVOKE_C, _OK_C, _FAIL_C, _INFO_C = 0, 1, 2, 3
+_TYPE_CODES = {INVOKE: _INVOKE_C, OK: _OK_C, FAIL: _FAIL_C}
+
+_process_of = attrgetter("process")
+_index_of = attrgetter("index")
+
+
+def _client_columns(ops: list) -> tuple["np.ndarray", "np.ndarray"]:
+    """(type codes int8, processes int64) of a list of client ops."""
+    codes = _TYPE_CODES
+    types = np.array([codes.get(o.type, _INFO_C) for o in ops],
+                     dtype=np.int8)
+    procs = np.fromiter(map(_process_of, ops), dtype=np.int64,
+                        count=len(ops))
+    return types, procs
+
+
+def _pair(types: "np.ndarray", procs: "np.ndarray"):
+    """Pairs invocations with completions over a run of client ops,
+    columnar, exactly as `PackedBuilder._append_client` does op by op.
+
+    After any op of process p, p's pending state is just "that op was
+    an invocation".  So along each process's events a completion pairs
+    with its predecessor iff that is an invocation, and an invocation
+    is superseded (indeterminate) iff its successor is one too.  A
+    stable sort by process lays each process's events side by side and
+    makes both relations shifted masks.
+
+    Returns `(inv, comp, tail, tail_start)`, positions into the run.
+    `inv`/`comp` are the rows in the order the per-op path emits them
+    (a paired row at its completion, a superseded invocation at the one
+    that supersedes it), `comp` -1 for a row without completion, rows
+    completed :fail dropped.  `tail` are the invocations left pending,
+    one per process, in the order they entered the pending dict:
+    `tail_start`, the first invocation of the process's trailing run.
+    """
+    m = types.shape[0]
+    key = procs
+    if m and -(2 ** 15) <= procs.min() and procs.max() < 2 ** 15:
+        # A stable sort of int16 is a radix sort, several times faster.
+        # jepsenlint: ignore[device.unguarded-narrowing] -- range checked above
+        key = procs.astype(np.int16)
+    order = np.argsort(key, kind="stable")
+    is_inv = types[order] == _INVOKE_C
+    ps = procs[order]
+    same = ps[1:] == ps[:-1]            # sorted j + 1 shares j's process
+    after_inv = np.zeros(m, dtype=bool)
+    after_inv[1:] = same & is_inv[:-1]
+    before_inv = np.zeros(m, dtype=bool)
+    before_inv[:-1] = same & is_inv[1:]
+    last = np.ones(m, dtype=bool)
+    last[:-1] = ~same
+    # Each row under the event that emits it; no event emits two.
+    by_emit = np.full(m, -1, dtype=np.int64)
+    j = np.flatnonzero(after_inv & ~is_inv)
+    by_emit[order[j]] = order[j - 1]
+    j = np.flatnonzero(before_inv & is_inv)
+    by_emit[order[j + 1]] = order[j]
+    at = np.flatnonzero(by_emit >= 0)
+    t = types[at]
+    live = t != _FAIL_C                 # :fail — certainly never happened
+    at = at[live]
+    inv = by_emit[at]
+    comp = np.where(t[live] == _INVOKE_C, -1, at)
+    # Each process's trailing run of invocations entered the pending
+    # dict at its first and stays there, its last as the value.
+    starts = np.where(is_inv & ~after_inv, np.arange(m), 0)
+    np.maximum.accumulate(starts, out=starts)
+    j = np.flatnonzero(is_inv & last)
+    tail_start = order[starts[j]]
+    by = np.argsort(tail_start, kind="stable")
+    return inv, comp, order[j][by], tail_start[by]
+
+
+def _encode_rows(encode: OpEncoderFn, ops: list, types: "np.ndarray",
+                 procs: "np.ndarray", events: "np.ndarray",
+                 inv: "np.ndarray", comp: "np.ndarray") -> "np.ndarray":
+    """Encodes the rows `_pair` found in the order given, the per-op
+    path's emit order: the model's interner assigns codes in the order
+    it first sees a value, so that order fixes a0/a1.  One call to the
+    encoder's batched form `encode.many` where it has one, else one
+    `encode` call per row.  Returns the rows the encoder keeps, in the
+    same order, as (r, 8) int64 rows like PackedBuilder's; `events`
+    maps a position in `ops` to its event index."""
+    invs = [ops[i] for i in inv.tolist()]
+    comps = [ops[c] if c >= 0 else None for c in comp.tolist()]
+    many = getattr(encode, "many", None)
+    if many is not None:
+        res = many(zip(invs, comps))
+    else:
+        res = list(map(encode, invs, comps))
+    kept = [r for r in res if r is not None]
+    codes = np.fromiter(chain.from_iterable(kept), dtype=np.int64)
+    if codes.shape[0] != 3 * len(kept):
+        raise ValueError("an op encoder returns (f, a0, a1) or None")
+    src = np.fromiter(map(_index_of, invs), dtype=np.int64,
+                      count=len(invs))
+    if len(kept) < len(res):
+        keep = np.fromiter((r is not None for r in res), dtype=bool,
+                           count=len(res))
+        inv, comp, src = inv[keep], comp[keep], src[keep]
+    ok = comp >= 0
+    ok[ok] = types[comp[ok]] == _OK_C
+    arr = np.empty((len(kept), 8), dtype=np.int64)
+    arr[:, 0] = events[inv]
+    arr[:, 1] = np.where(ok, events[comp], NO_RET)
+    arr[:, 2] = procs[inv]
+    arr[:, 3] = np.where(ok, ST_OK, ST_INFO)
+    arr[:, 4:7] = codes.reshape(-1, 3)
+    arr[:, 7] = src
+    return arr
+
+
+#: Below this many client events pack_history takes the per-op path:
+#: the columnar pass's fixed numpy cost outweighs what it saves (a
+#: jepsen.independent key is a few hundred events).
+_PACK_MIN = 448
+
+
 def pack_history(h: History, encode: OpEncoderFn) -> PackedOps:
     """Packs the client portion of a history into columnar arrays.
 
@@ -692,85 +743,32 @@ def pack_history(h: History, encode: OpEncoderFn) -> PackedOps:
          (ret = NO_RET);
       5. encode (f, value) via the model's encoder; encoders may drop
          no-effect indeterminate ops (e.g. :info reads).
+
+    From `_PACK_MIN` client events up, one columnar pass (`_pair`,
+    `_encode_rows`) over the whole history; below, the builder's per-op
+    path.  The per-op reference is `PackedBuilder.append` + `finish`:
+    the columnar pass returns the same bytes (`packed_to_bytes`) on the
+    same ops and a fresh encoder, rows reaching the encoder in the same
+    order — at their completion, at the invocation that supersedes
+    them, and the unfinished last, in the order their processes entered
+    the pending dict.
     """
-    client = [o for o in h if o.is_client_op]
-    rows: list[tuple[int, int, int, int, int, int, int, int]] = []
-    # Re-derive pairing on the client-only event sequence so inv/ret indices
-    # are dense event positions in that sequence.
-    pending: dict[Any, tuple[int, Op]] = {}
-    events: list[tuple[Op, int]] = [(o, e) for e, o in enumerate(client)]
-
-    def emit(inv_e: int, invoke_op: Op, ret_e: int, comp: Op | None) -> None:
-        if comp is not None and comp.type == FAIL:
-            return  # certainly never happened
-        status = ST_OK if (comp is not None and comp.type == OK) else ST_INFO
-        enc = encode(invoke_op, comp)
-        if enc is None:
-            return
-        fc, a0, a1 = enc
-        rows.append(
-            (
-                inv_e,
-                ret_e if status == ST_OK else NO_RET,
-                invoke_op.process,
-                status,
-                fc,
-                a0,
-                a1,
-                invoke_op.index,
-            )
-        )
-
-    for o, e in events:
-        if o.type == INVOKE:
-            prev = pending.get(o.process)
-            if prev is not None:
-                # Double invoke without completion (torn history): the
-                # earlier op is indeterminate, like core pairing keeps it.
-                emit(prev[0], prev[1], -1, None)
-            pending[o.process] = (e, o)
-        else:
-            inv = pending.pop(o.process, None)
-            if inv is None:
-                continue  # completion without invocation: tolerate
-            inv_e, inv_op = inv
-            emit(inv_e, inv_op, e, o)
-    # Unfinished invocations are indeterminate.
-    for inv_e, inv_op in pending.values():
-        emit(inv_e, inv_op, -1, None)
-
-    rows.sort(key=lambda r: r[0])
-    if rows:
-        arr = np.array(rows, dtype=np.int64)
-    else:
-        arr = np.zeros((0, 8), dtype=np.int64)
-
-    inv = arr[:, 0]
-    ret = arr[:, 1]
-    n = arr.shape[0]
-    _require_i32(arr)
-
-    # preds[a] = #{y != a : ret(y) < inv(a)}
-    # horizon[a] = #{y != a : inv(y) < ret(a)}
-    # O(n log n) via sorted ret values.
-    ret_sorted = np.sort(ret)
-    preds = np.searchsorted(ret_sorted, inv, side="left").astype(np.int64)
-    # inv is sorted ascending already; count invs strictly below each ret.
-    inv_before_ret = np.searchsorted(inv, ret, side="left").astype(np.int64)
-    # Subtract self when inv(a) < ret(a) (always true for completed ops;
-    # for NO_RET ops every other op counts, self too — subtract 1).
-    horizon = inv_before_ret - 1
-    horizon = np.minimum(horizon, n - 1)
-
-    return PackedOps(
-        inv=inv.astype(np.int64),
-        ret=ret,
-        process=arr[:, 2].astype(np.int32),
-        status=arr[:, 3].astype(np.int32),
-        f=arr[:, 4].astype(np.int32),
-        a0=arr[:, 5].astype(np.int32),
-        a1=arr[:, 6].astype(np.int32),
-        src_index=arr[:, 7].astype(np.int64),
-        preds=preds,
-        horizon=horizon,
-    )
+    client = [o for o in h if isinstance(o.process, int)]
+    if len(client) < _PACK_MIN:
+        telemetry.count("ingest.pack.scalar")
+        b = PackedBuilder(encode)
+        for o in client:
+            b._append_client(o)
+        packed = b._close()
+        telemetry.count("ingest.pack.rows", packed.n)
+        return packed
+    types, procs = _client_columns(client)
+    inv, comp, tail, _ = _pair(types, procs)
+    inv = np.concatenate([inv, tail])
+    comp = np.concatenate([comp, np.full(tail.shape[0], -1, dtype=np.int64)])
+    if not hasattr(encode, "many"):
+        telemetry.count("ingest.pack.scalar")
+    arr = _encode_rows(encode, client, types, procs,
+                       np.arange(len(client), dtype=np.int64), inv, comp)
+    telemetry.count("ingest.pack.rows", arr.shape[0])
+    return _packed(arr[np.argsort(arr[:, 0])], with_preds=True)

@@ -99,11 +99,12 @@ def _register_packed(model: Register, allow_cas: bool) -> PackedModel:
         raise ValueError(f"register model can't encode op f {f!r}")
 
     def encode_many(items):
-        # Columnar-ingest hook (PackedBuilder.append_many): encode() over
-        # a [(inv, comp)] batch with the interner inlined — one loop,
-        # no per-op intern_value/intern call frames.  MUST stay
-        # semantically in lockstep with encode(): same interner dicts,
-        # same drops, same codes, so the packed bytes are identical.
+        # Columnar-ingest hook (pack_history, PackedBuilder.append_many):
+        # encode() over an iterable of (inv, comp) pairs, in order, with
+        # the interner inlined — one loop, no per-op intern_value/intern
+        # call frames.  MUST stay semantically in lockstep with encode():
+        # same interner dicts, same drops, same codes, so the packed
+        # bytes are identical.
         ids = interner._ids
         vals = interner.values
         out = []

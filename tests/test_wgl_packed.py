@@ -355,24 +355,40 @@ def test_packed_fallback_counter(monkeypatch):
 
 
 def test_append_many_byte_parity_fuzz():
-    pm = cas_register().packed()
+    # Each side encodes with a fresh encoder, so the interner's codes
+    # are compared too: they follow the order rows reach the encoder.
+    def fresh():
+        return cas_register().packed().encode
+
     rng = np.random.default_rng(29)
-    for trial in range(12):
-        n = int(rng.integers(1, 300))
-        h = random_register_history(
-            n, procs=int(rng.integers(1, 7)),
-            info_rate=float(rng.uniform(0, 0.3)),
-            seed=int(rng.integers(0, 1 << 30)),
-        )
+    for trial in range(13):
+        if trial == 0:
+            # Process 7 writes first and 0 writes next: interning in
+            # process order instead of emit order gives other codes.
+            h = history([
+                Op(type="invoke", f="write", value=40, process=7),
+                Op(type="invoke", f="write", value=41, process=0),
+                Op(type="ok", f="write", value=40, process=7),
+                Op(type="ok", f="write", value=41, process=0),
+            ] + list(random_register_history(300, procs=5, seed=1)))
+        else:
+            h = random_register_history(
+                int(rng.integers(1, 300)), procs=int(rng.integers(1, 7)),
+                info_rate=float(rng.uniform(0, 0.3)),
+                seed=int(rng.integers(0, 1 << 30)),
+            )
         ops = list(h)
-        ref = packed_to_bytes(pack_history(h, pm.encode))
-        scalar = PackedBuilder(pm.encode)
+        ref = packed_to_bytes(pack_history(h, fresh()))
+        scalar = PackedBuilder(fresh())
         for o in ops:
             scalar.append(o)
         assert packed_to_bytes(scalar.finish()) == ref
+        whole = PackedBuilder(fresh())
+        whole.append_many(ops)
+        assert packed_to_bytes(whole.finish()) == ref, f"trial {trial}"
         # Random chunking, including tiny chunks (the scalar fallback)
         # and chunks that split invoke/completion pairs across calls.
-        chunked = PackedBuilder(pm.encode)
+        chunked = PackedBuilder(fresh())
         i = 0
         while i < len(ops):
             c = int(rng.integers(1, 80))
