@@ -548,21 +548,33 @@ def check_wgl_device(
         ) as _pw:
             _pw.knob(info_window=NARROW_INFO_WINDOW,
                      width_hint=width_hint)
+            # Each rung and the re-plan between them has a span inside
+            # `wgl.witness`, which the benchmark's `witness_s` reads
+            # whole.
             with telemetry.span("wgl.witness"):
-                wres = check_wgl_witness(
-                    packed, pm, info_window=NARROW_INFO_WINDOW,
-                    time_limit_s=remaining(), width_hint=width_hint,
-                    checkpoint_dir=checkpoint_dir,
-                )
-                if wres is None and not timed_out() and plan_drops(
-                    packed, info_window=NARROW_INFO_WINDOW
-                ):
-                    _pw.knob(info_window=WIDE_INFO_WINDOW)
+                with telemetry.span("wgl.witness.narrow"):
                     wres = check_wgl_witness(
-                        packed, pm, info_window=WIDE_INFO_WINDOW,
+                        packed, pm, info_window=NARROW_INFO_WINDOW,
                         time_limit_s=remaining(), width_hint=width_hint,
                         checkpoint_dir=checkpoint_dir,
                     )
+                if wres is None:
+                    telemetry.count("wgl.witness.narrow-miss")
+                    if not timed_out():
+                        with telemetry.span("wgl.witness.plan-drops"):
+                            drops = plan_drops(
+                                packed, info_window=NARROW_INFO_WINDOW)
+                        if drops:
+                            _pw.knob(info_window=WIDE_INFO_WINDOW)
+                            telemetry.count("wgl.witness.widened")
+                            with telemetry.span("wgl.witness.wide"):
+                                wres = check_wgl_witness(
+                                    packed, pm,
+                                    info_window=WIDE_INFO_WINDOW,
+                                    time_limit_s=remaining(),
+                                    width_hint=width_hint,
+                                    checkpoint_dir=checkpoint_dir,
+                                )
             _pw.outcome = "hit" if wres is not None else "miss"
         if wres is not None:
             telemetry.count("wgl.witness.hit")
