@@ -185,6 +185,37 @@ def _state_hash_vec(sw: int, seed: int = 0xA11CE) -> np.ndarray:
     return rng.uniform(1.0, 2.0, size=(sw,)).astype(np.float32)
 
 
+#: The hash of a chain-round candidate that is no child.
+NO_CHILD = np.float32(3.0e38)
+
+
+def _pick_children(h, child_states, B: int):
+    """At most B candidates of distinct state, by hash.
+
+    `h` (M,) f32 is each candidate's state hash, NO_CHILD where it is
+    no child; `child_states` (M, SW) its state.  Each of B passes keeps
+    the candidate of least hash (lowest index on a tie) and masks every
+    candidate of that hash and that state, so the kept candidates come
+    in ascending (hash, index) order.  Equal states always hash equal;
+    distinct states whose hashes collide are each kept.  Returns `pos`
+    (B,), the kept indices, padded with the first; and `found` (B,)
+    bool, true for the passes that kept one (a prefix).
+    """
+    import jax.numpy as jnp
+
+    picks, found = [], []
+    for _ in range(B):
+        i = jnp.argmin(h)
+        picks.append(i)
+        found.append(h[i] < NO_CHILD)
+        h = jnp.where(
+            (h == h[i]) & (child_states == child_states[i]).all(axis=1),
+            NO_CHILD, h,
+        )
+    found = jnp.stack(found)
+    return jnp.where(found, jnp.stack(picks), picks[0]), found
+
+
 def _plan_blocks(packed: PackedOps, bars_per_block: int,
                  info_window: Optional[int] = None,
                  rank_override: Optional[np.ndarray] = None):
@@ -463,8 +494,7 @@ def _make_pallas_sweep(B: int, W: int, SW: int, K: int, jax_step_rows,
 
 def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                    jax_step, pallas_mode: str = "off",
-                   jax_step_rows=None, compact: int = 0,
-                   packed: bool = False):
+                   jax_step_rows=None, packed: bool = False):
     """One call runs NB blocks of up to K barriers each.
 
     Args: member (W, B) bool — window-major so the per-barrier
@@ -488,26 +518,18 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
     is visited exactly once.
 
     Flat (helper, lane) pair indexing is helper-major: i = h*B + lane.
+    A chain round runs the pair step over the whole (W, B) tile and
+    keeps at most B children with B masked-min passes over the
+    candidates' state hashes: no sort, no scatter, no cumsum.
 
-    `compact` (static, 0 = off) is the candidate-compaction tile width:
-    round-3 profiling measured 50-90% of the (W, B) pair lanes masked
-    out by `avail` in the chain rounds (which are 85-89% of witness
-    time).  When the number of window rows with ANY available lane fits
-    in `compact`, the heavy round gathers just those rows into a
-    (compact, B) tile — the batched pair-step and the argsort dedup
-    then run over compact*B candidates instead of W*B — and maps the
-    winners back to window columns through the gather index.  Overflow
-    falls back to the uncompacted path behind a lax.cond (the engine's
-    standard escalation pattern), so results are bit-identical.
+    Every entry also returns `rounds`, the number of chain rounds the
+    call ran (counter `wgl.witness.chain-rounds`).
     """
     import jax
     import jax.numpy as jnp
 
     col = jnp.arange(W)
     hv = jnp.asarray(_state_hash_vec(SW))
-    BIG = jnp.float32(3.0e38)
-    M = B * W
-    WC = compact if 0 < compact < W else 0
 
     # `packed`: the (W, B) member window rides the inter-block scan
     # carry — and the per-block re-gather, the engine's hottest
@@ -547,32 +569,21 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                 jnp.repeat(a1_r, B),
             )
 
-        def select_children(member, child_states, good, row_map):
+        def select_children(member, child_states, good):
             """Dedup (helper, lane) children by model state, keep <= B.
 
             Selection happens over flat-pair scalars FIRST; member
             columns are materialized only for the <= B winners —
-            building (M, W) child-member matrices up front costs
-            ~B*W*W bytes.  Hash-sort + exact adjacent compare: equal
-            states always hash equal; collisions only cost beam slots.
-            `row_map` maps tile rows back to window columns (identity
-            for the uncompacted path)."""
-            h = jnp.where(good, child_states.astype(jnp.float32) @ hv, BIG)
-            order = jnp.argsort(h)
-            hs = h[order]
-            ss = child_states[order]
-            same = (hs == jnp.roll(hs, 1)) & (
-                ss == jnp.roll(ss, 1, axis=0)
-            ).all(axis=1)
-            same = same.at[0].set(False)
-            uniq = (hs < BIG) & ~same
-            n_child = jnp.minimum(uniq.sum(), B)
-            pos = order[jnp.nonzero(uniq, size=B, fill_value=0)[0]]
-            hcol = row_map[pos // B]
+            building (W*B, W) child-member matrices up front costs
+            ~B*W*W bytes."""
+            h = jnp.where(good, child_states.astype(jnp.float32) @ hv,
+                          NO_CHILD)
+            pos, found = _pick_children(h, child_states, B)
             lane = pos % B
-            new_member = member[:, lane] | (col[:, None] == hcol[None, :])
-            new_alive = jnp.arange(B) < n_child
-            return new_member, child_states[pos], new_alive
+            new_member = member[:, lane] | (
+                col[:, None] == (pos // B)[None, :]
+            )
+            return new_member, child_states[pos], found
 
         def heavy(member, states, alive, a, r, bf, ba0, ba1, k_rank):
             """Chain search at one barrier: direct -> targeted h·a ->
@@ -602,21 +613,15 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                 new_states = jnp.where(surv_dir[:, None], ns, states)
                 return member, new_states, new_alive
 
-            def run_tile(member, states, avail, row_map, f_r, a0_r,
-                         a1_r):
-                """One fused escalation over a (R, B) candidate tile:
-                the helper pair-step is evaluated ONCE and feeds both
-                the targeted test (helper+barrier legal -> done) and
-                the expand-any fallback (any productive helper -> keep
-                searching).  Round-2's split version recomputed
-                pair_steps and ran select_children twice behind an
-                extra lax.cond — the chain rounds are ~88% of witness
-                time (see tools/profile_witness.py), so the duplicated
-                work was the engine's single hottest redundancy."""
-                R = row_map.shape[0]
-                flat = avail.reshape(-1)
-                states_rep = jnp.tile(states, (R, 1))
-                s1, legal1 = pair_steps(states_rep, f_r, a0_r, a1_r)
+            def targeted_or_expand(member, states, alive):
+                """One fused escalation over the (W, B) candidate
+                tile: the helper pair-step is evaluated ONCE and feeds
+                both the targeted test (helper+barrier legal -> done)
+                and the expand-any fallback (any productive helper ->
+                keep searching)."""
+                flat = helper_avail(member, alive).reshape(-1)
+                states_rep = jnp.tile(states, (W, 1))
+                s1, legal1 = pair_steps(states_rep, f_w, a0_w, a1_w)
                 s2, legal2 = jax.vmap(step_bar)(s1)
                 good_t = flat & legal1 & legal2
                 ok2 = good_t.any()
@@ -624,49 +629,15 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                 good_e = flat & productive
                 child = jnp.where(ok2, s2, s1)
                 good = jnp.where(ok2, good_t, good_e)
-                cm, cs, ca = select_children(member, child, good,
-                                             row_map)
+                cm, cs, ca = select_children(member, child, good)
                 return cm, cs, ca, ok2
 
-            def targeted_or_expand(member, states, alive):
-                """Chain-round escalation with candidate compaction:
-                gather the window rows that still have an available
-                (helper, lane) pair into a (WC, B) tile when they fit
-                (the 50-90%-masked common case measured in round 3),
-                else run the full (W, B) tile.  Candidate order is
-                preserved by the ascending gather, so both branches
-                select identical children — the cond trades nothing
-                but compile time."""
-                avail_full = helper_avail(member, alive)  # (W, B)
-                if WC == 0:
-                    return run_tile(member, states, avail_full, col,
-                                    f_w, a0_w, a1_w)
-
-                row_any = avail_full.any(axis=1)
-                n_av = row_any.sum()
-
-                def compact_path(_):
-                    idx = jnp.nonzero(row_any, size=WC,
-                                      fill_value=0)[0]
-                    avail_c = avail_full[idx] & (
-                        jnp.arange(WC) < n_av
-                    )[:, None]
-                    return run_tile(member, states, avail_c, idx,
-                                    f_w[idx], a0_w[idx], a1_w[idx])
-
-                def full_path(_):
-                    return run_tile(member, states, avail_full, col,
-                                    f_w, a0_w, a1_w)
-
-                return jax.lax.cond(n_av <= WC, compact_path,
-                                    full_path, None)
-
             def cond(c):
-                _, _, alive, done, d = c
+                _, _, alive, done, d, _ = c
                 return (~done) & (d < D) & alive.any()
 
             def body(c):
-                member, states, alive, _, d = c
+                member, states, alive, _, d, rounds = c
                 m1, s1, al1 = try_direct(member, states, alive)
 
                 def on_direct(_):
@@ -678,51 +649,54 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                 mN, sN, alN, done = jax.lax.cond(
                     al1.any(), on_direct, no_direct, None
                 )
-                return mN, sN, alN, done, d + 1
+                return (mN, sN, alN, done, d + 1,
+                        rounds + jnp.where(al1.any(), 0, 1))
 
-            member, states, alive, done, _ = jax.lax.while_loop(
-                cond, body, (member, states, alive, False, 0)
+            member, states, alive, done, _, rounds = jax.lax.while_loop(
+                cond, body,
+                (member, states, alive, False, 0, jnp.int32(0)),
             )
-            return member, states, alive, done
+            return member, states, alive, done, rounds
 
         if pallas_sweep is not None:
             # ---- pallas hybrid: VMEM sweep to the next death point,
             # heavy in XLA, resume — all under one while_loop ----
             def cond_w(c):
-                k, _, _, _, failed, _ = c
+                k, _, _, _, failed, _, _ = c
                 return (k < K) & ~failed
 
             def body_w(c):
-                k, member, states, alive, failed, died = c
+                k, member, states, alive, failed, died, rounds = c
                 s2, al2, dk = pallas_sweep(k, bars, member, states, alive)
 
                 def clean(_):
-                    return jnp.int32(K), member, s2, al2, failed, died
+                    return (jnp.int32(K), member, s2, al2, failed, died,
+                            rounds)
 
                 def death(_):
                     colv = jax.lax.dynamic_slice(
                         bars, (jnp.int32(0), dk), (6, 1)
                     )[:, 0]
-                    m, s, al, done = heavy(
+                    m, s, al, done, r = heavy(
                         member, s2, al2, colv[0], colv[1], colv[3],
                         colv[4], colv[5], k0 + dk,
                     )
                     d2 = jnp.where(~done & (died == NO_BAR),
                                    k0 + dk, died)
-                    return dk + 1, m, s, al, failed | ~done, d2
+                    return (dk + 1, m, s, al, failed | ~done, d2,
+                            rounds + r)
 
                 return jax.lax.cond(dk >= K, clean, death, None)
 
-            _, member, states, alive, failed, died = jax.lax.while_loop(
+            return jax.lax.while_loop(
                 cond_w, body_w,
                 (jnp.int32(0), member, states, alive, jnp.bool_(False),
-                 jnp.int32(NO_BAR)),
-            )
-            return member, states, alive, failed, died
+                 jnp.int32(NO_BAR), jnp.int32(0)),
+            )[1:]
 
         # ---- barrier scan: pass/direct inline, heavy behind a cond ----
         def body(carry, xs):
-            member, states, alive, failed, died = carry
+            member, states, alive, failed, died, rounds = carry
             a, r, real, bf, ba0, ba1, k = xs
             has = member[a]
             ns, legal = jax.vmap(
@@ -737,14 +711,14 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
                 commit = active & new_alive.any()
                 st = jnp.where((commit & surv_dir)[:, None], ns, states)
                 al = jnp.where(commit, new_alive, alive)
-                return member, st, al, failed, died
+                return member, st, al, failed, died, rounds
 
             def hard(_):
-                m, s, al, done = heavy(
+                m, s, al, done, n = heavy(
                     member, states, alive, a, r, bf, ba0, ba1, k0 + k
                 )
                 d2 = jnp.where(~done & (died == NO_BAR), k0 + k, died)
-                return m, s, al, failed | ~done, d2
+                return m, s, al, failed | ~done, d2, rounds + n
 
             out = jax.lax.cond(
                 active & ~new_alive.any(), hard, easy, None
@@ -752,47 +726,55 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
             return out, None
 
         carry0 = (member, states, alive, jnp.bool_(False),
-                  jnp.int32(NO_BAR))
-        (member, states, alive, failed, died), _ = jax.lax.scan(
+                  jnp.int32(NO_BAR), jnp.int32(0))
+        out, _ = jax.lax.scan(
             body, carry0,
             (bars[0], bars[1], bars[2], bars[3], bars[4], bars[5],
              jnp.arange(K, dtype=jnp.int32)),
         )
-        return member, states, alive, failed, died
+        return out
+
+    def guarded_block(member, states, alive, failed, died, rounds,
+                      bars_b, tab_b, k0):
+        """run_block unless an earlier block failed; member arrives and
+        leaves in carry form (_pack_m)."""
+        def run(_):
+            m, s, al, f2, d2, n = run_block(
+                _unpack_m(member), states, alive, bars_b, tab_b, k0
+            )
+            return _pack_m(m), s, al, f2, d2, n
+
+        def skip(_):
+            return (member, states, alive, jnp.bool_(False),
+                    jnp.int32(NO_BAR), jnp.int32(0))
+
+        m, s, al, f2, d2, n = jax.lax.cond(~failed, run, skip, None)
+        died = jnp.where((d2 != NO_BAR) & (died == NO_BAR), d2, died)
+        return m, s, al, failed | f2, died, rounds + n
+
+    def carry0(member, states, alive, failed):
+        return (_pack_m(member), states, alive, failed,
+                jnp.int32(NO_BAR), jnp.int32(0))
 
     def chunk(member, states, alive, failed, bars, tab, perm, present,
               k0s):
         def body(carry, xs):
-            member, states, alive, failed, died = carry
+            member, *rest = carry
             bars_b, tab_b, perm_b, present_b, k0 = xs
             member = jnp.where(present_b[:, None], member[perm_b],
                                zero_m)
+            return guarded_block(member, *rest, bars_b, tab_b, k0), None
 
-            def run(_):
-                m, s, al, f2, d2 = run_block(
-                    _unpack_m(member), states, alive, bars_b, tab_b, k0
-                )
-                return _pack_m(m), s, al, f2, d2
-
-            def skip(_):
-                return (member, states, alive, jnp.bool_(False),
-                        jnp.int32(NO_BAR))
-
-            m, s, al, f2, d2 = jax.lax.cond(~failed, run, skip, None)
-            died = jnp.where((d2 != NO_BAR) & (died == NO_BAR), d2, died)
-            return (m, s, al, failed | f2, died), None
-
-        (member, states, alive, failed, died), _ = jax.lax.scan(
-            body,
-            (_pack_m(member), states, alive, failed, jnp.int32(NO_BAR)),
+        (member, *rest), _ = jax.lax.scan(
+            body, carry0(member, states, alive, failed),
             (bars, tab, perm, present, k0s),
         )
-        return _unpack_m(member), states, alive, failed, died
+        return (_unpack_m(member), *rest)
 
     jcol = jnp.arange(K, dtype=jnp.int32)
     wcol = jnp.arange(W, dtype=jnp.int32)
 
-    def idx_block_step(member, states, alive, failed, died,
+    def idx_block_step(member, states, alive, failed, died, rounds,
                        bar_b, act_b, nb, nw, perm_b, present_b,
                        k0, fA, a0A, a1A, retA, invA, rankA):
         """One block: regather member (packed lanes when enabled),
@@ -818,20 +800,8 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
             jnp.where(valid_w, a1A[act_b], 0),
             jnp.where(valid_w, rankA[act_b], NO_BAR),
         ])
-
-        def run(_):
-            m, s, al, f2, d2 = run_block(
-                _unpack_m(member), states, alive, bars_b, tab_b, k0
-            )
-            return _pack_m(m), s, al, f2, d2
-
-        def skip(_):
-            return (member, states, alive, jnp.bool_(False),
-                    jnp.int32(NO_BAR))
-
-        m, s, al, f2, d2 = jax.lax.cond(~failed, run, skip, None)
-        died = jnp.where((d2 != NO_BAR) & (died == NO_BAR), d2, died)
-        return m, s, al, failed | f2, died
+        return guarded_block(member, states, alive, failed, died, rounds,
+                             bars_b, tab_b, k0)
 
     def chunk_idx(member, states, alive, failed, bar_idx, act_idx,
                   nbars, nws, perm, present, k0s,
@@ -848,21 +818,16 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
         gathers clamp under jit and the nw mask discards the lanes).
         """
         def body(carry, xs):
-            member, states, alive, failed, died = carry
-            bar_b, act_b, nb, nw, perm_b, present_b, k0 = xs
             out = idx_block_step(
-                member, states, alive, failed, died,
-                bar_b, act_b, nb, nw, perm_b, present_b, k0,
-                fA, a0A, a1A, retA, invA, rankA,
+                *carry, *xs, fA, a0A, a1A, retA, invA, rankA,
             )
             return out, None
 
-        (member, states, alive, failed, died), _ = jax.lax.scan(
-            body,
-            (_pack_m(member), states, alive, failed, jnp.int32(NO_BAR)),
+        (member, *rest), _ = jax.lax.scan(
+            body, carry0(member, states, alive, failed),
             (bar_idx, act_idx, nbars, nws, perm, present, k0s),
         )
-        return _unpack_m(member), states, alive, failed, died
+        return (_unpack_m(member), *rest)
 
     def make_chunk_dev(S: int):
         """Builds the transfer="device" entry for span-slice width S.
@@ -905,7 +870,7 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
         scol = jnp.arange(S, dtype=jnp.int32)
 
         def body(carry, xs):
-            member, states, alive, failed, died, prev_act = carry
+            *block_carry, prev_act = carry
             k0, er, lo, nb, cut = xs
             rows = lo + scol
             rows_c = jnp.minimum(rows, n_total - 1)
@@ -928,7 +893,7 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
             perm_b = jnp.where(present_b, pos_c, 0)
             bar_b = jax.lax.dynamic_slice(barsA, (k0,), (K,))
             out = idx_block_step(
-                member, states, alive, failed, died,
+                *block_carry,
                 bar_b, act_b, nb, nw, perm_b, present_b, k0,
                 fA, a0A, a1A, retA, invA, rankA,
             )
@@ -939,8 +904,7 @@ def _make_chunk_fn(B: int, W: int, SW: int, K: int, D: int, NB: int,
 
         carry, _ = jax.lax.scan(
             body,
-            (_pack_m(member), states, alive, failed, jnp.int32(NO_BAR),
-             prev_act),
+            (*carry0(member, states, alive, failed), prev_act),
             (k0s, end_rets, los, nbars, cuts),
         )
         return (_unpack_m(carry[0]),) + tuple(carry[1:])
@@ -965,7 +929,6 @@ def check_wgl_witness(
     width_hint: int = 0,
     time_limit_s: Optional[float] = None,
     pallas: str = "auto",
-    compact: int = -1,
     checkpoint_dir: Optional[str] = None,
     transfer: str = "auto",
     rank_override: Optional[np.ndarray] = None,
@@ -1011,16 +974,6 @@ def check_wgl_witness(
     scan.  `bars_per_block` beyond what SMEM holds
     (`pallas_smem_bytes`) raises ValueError before the kernel is built.
 
-    `compact`: chain-round candidate-compaction tile width.  -1 picks
-    max(64, min(W // 2, info_window)) — or max(64, W // 8) when
-    info_window is None: available helpers at a chain round are
-    almost all info columns, which the window bound caps at
-    info_window, so a tile of exactly that width fits nearly every
-    round (measured on the 100k bench config: compact=512 = the
-    narrow window is 2.9x end-to-end vs off, while W//8 = 256
-    overflows to the full tile at most barriers and wins only 7%).
-    0 disables.
-
     `rank_override`: optional (n,) int array giving NON-barrier rows a
     synthetic barrier rank (-1 = no override).  Once that rank passes,
     the row behaves like a retired barrier: implied membership,
@@ -1034,6 +987,9 @@ def check_wgl_witness(
     failure, "died_at_rank" is the global rank of the first barrier
     the chain search could not linearize (None if the death point was
     not localized).
+
+    With telemetry on, counter `wgl.witness.chain-rounds` grows by the
+    chain rounds the search ran.
     """
     import jax
     import jax.numpy as jnp
@@ -1120,11 +1076,6 @@ def check_wgl_witness(
         )
     telemetry.count(f"wgl.witness.pallas-{pallas}")
 
-    if compact < 0:
-        compact = max(64, min(
-            W // 2, info_window if info_window is not None else W // 8
-        ))
-
     if transfer not in ("auto", "full", "indices", "device"):
         raise ValueError(f"unknown transfer mode {transfer!r}")
     if transfer == "auto":
@@ -1197,7 +1148,7 @@ def check_wgl_witness(
                 blocks_per_call=blocks_per_call, depth=depth,
                 info_window=info_window, max_window=max_window,
                 width_hint=width_hint, time_limit_s=rem,
-                pallas=pallas, compact=compact,
+                pallas=pallas,
                 checkpoint_dir=checkpoint_dir, transfer=transfer,
                 rank_override=rank_override, out_info=out_info,
                 packed_lanes=False, _degraded=_degraded,
@@ -1226,7 +1177,7 @@ def check_wgl_witness(
             blocks_per_call=max(blocks_per_call // 2, 1), depth=depth,
             info_window=info_window, max_window=max_window,
             width_hint=width_hint, time_limit_s=remaining,
-            pallas=pallas, compact=compact,
+            pallas=pallas,
             checkpoint_dir=checkpoint_dir, transfer=transfer,
             rank_override=rank_override, out_info=out_info,
             packed_lanes=packed_on, _degraded=True,
@@ -1235,7 +1186,7 @@ def check_wgl_witness(
     # The step fn itself keys the cache (strong ref): an id() key
     # can collide after GC address reuse and serve the wrong
     # model's transition kernel.
-    key = (B, W, SW, K, D, NB, pm.jax_step, pallas, compact, packed_on)
+    key = (B, W, SW, K, D, NB, pm.jax_step, pallas, packed_on)
     # jax.jit is lazy: a freshly built chunk fn actually compiles on
     # its FIRST call — the trace labels that call "compile".
     fresh_fn = False
@@ -1245,7 +1196,7 @@ def check_wgl_witness(
         fns = _make_chunk_fn(B, W, SW, K, D, NB, pm.jax_step,
                              pallas_mode=pallas,
                              jax_step_rows=pm.jax_step_rows,
-                             compact=compact, packed=packed_on)
+                             packed=packed_on)
         _chunk_fn_cache[key] = fns
     fn, fn_idx, make_dev = fns
     fn_dev = None
@@ -1443,14 +1394,14 @@ def check_wgl_witness(
             # its duration is real device time, not async enqueue.
             with sp:
                 if transfer == "device":
-                    (member, states, alive, failed, died,
+                    (member, states, alive, failed, died, rounds,
                      prev_act_dev) = fn_dev(
                         member, states, alive, failed, prev_act_dev,
                         *dev_args, jnp.int32(packed.n),
                         *row_tables, icumA, barsA,
                     )
                 elif transfer == "indices":
-                    member, states, alive, failed, died = fn_idx(
+                    member, states, alive, failed, died, rounds = fn_idx(
                         member, states, alive, failed,
                         jnp.asarray(bar_idx_np), jnp.asarray(act_idx_np),
                         jnp.asarray(nbars_np), jnp.asarray(nws_np),
@@ -1458,7 +1409,7 @@ def check_wgl_witness(
                         jnp.asarray(k0s_np), *row_tables,
                     )
                 else:
-                    member, states, alive, failed, died = fn(
+                    member, states, alive, failed, died, rounds = fn(
                         member, states, alive, failed,
                         jnp.asarray(bars_np), jnp.asarray(tab_np),
                         jnp.asarray(perm_np), jnp.asarray(present_np),
@@ -1469,6 +1420,8 @@ def check_wgl_witness(
                 # dispatch is asynchronous, so execution-time failures
                 # only raise when a result is consumed.
                 failed_now = bool(failed)
+            if telemetry.enabled():
+                telemetry.count("wgl.witness.chain-rounds", int(rounds))
         except Exception as e:
             if degrade.is_resource_error(e):
                 # The device (not the search) gave out: degradation

@@ -163,18 +163,14 @@ class FrontierCarry:
         Shares ops/wgl_witness.py's cache (same key scheme) so a
         post-hoc witness run at the same shape reuses the compile."""
         W = self._W
-        compact = max(64, min(
-            W // 2,
-            self.info_window if self.info_window is not None else W // 8,
-        ))
         key = (self.B, W, self.pm.state_width, self.K, self.D, self.NB,
-               self.pm.jax_step, "off", compact)
+               self.pm.jax_step, "off", False)
         fns = _chunk_fn_cache.get(key)
         if fns is None:
             fns = _make_chunk_fn(
                 self.B, W, self.pm.state_width, self.K, self.D, self.NB,
                 self.pm.jax_step, pallas_mode="off",
-                jax_step_rows=self.pm.jax_step_rows, compact=compact,
+                jax_step_rows=self.pm.jax_step_rows,
             )
             _chunk_fn_cache[key] = fns
         return fns[0]  # transfer="full" entry
@@ -249,13 +245,16 @@ class FrontierCarry:
             try:
                 with telemetry.span("wgl.online.chunk",
                                     blocks=len(chunk_blocks)):
-                    member, states, alive, failed, died = fn(
+                    member, states, alive, failed, died, rounds = fn(
                         member, states, alive, failed,
                         jnp.asarray(bars_np), jnp.asarray(tab_np),
                         jnp.asarray(perm_np), jnp.asarray(present_np),
                         jnp.asarray(k0s_np),
                     )
                     failed_now = bool(failed)
+                if telemetry.enabled():
+                    telemetry.count("wgl.witness.chain-rounds",
+                                    int(rounds))
             except Exception as e:  # noqa: BLE001
                 # Any device/compile failure mid-run: mark dead and let
                 # the post-hoc ladder (with its own degradation rungs)

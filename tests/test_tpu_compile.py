@@ -67,8 +67,7 @@ def test_witness_chunk_pallas_on_default_block(one_chip):
     W, K, NB, n = W_NORTH_STAR, K_DEFAULT, NB_DEFAULT, N_NORTH_STAR
     _, _, make_dev = _make_chunk_fn(
         B_WITNESS, W, pm.state_width, K, 5, NB, pm.jax_step,
-        pallas_mode="on", jax_step_rows=pm.jax_step_rows, compact=512,
-        packed=True,
+        pallas_mode="on", jax_step_rows=pm.jax_step_rows, packed=True,
     )
     s = lambda shape, dt=jnp.int32: _spec(shape, dt, one_chip)  # noqa: E731
     args = (

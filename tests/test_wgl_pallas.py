@@ -128,7 +128,7 @@ def test_pallas_runtime_failure_raises(monkeypatch):
     calls = []
 
     def fake_make(B, W, SW, K, D, NB, jax_step, pallas_mode="off",
-                  jax_step_rows=None, compact=0, packed=False):
+                  jax_step_rows=None, packed=False):
         calls.append(pallas_mode)
         if pallas_mode == "on":
             def boom(*a, **k):
@@ -138,8 +138,7 @@ def test_pallas_runtime_failure_raises(monkeypatch):
             return boom, boom, boom
         return real_make(B, W, SW, K, D, NB, jax_step,
                          pallas_mode=pallas_mode,
-                         jax_step_rows=jax_step_rows,
-                         compact=compact, packed=packed)
+                         jax_step_rows=jax_step_rows, packed=packed)
 
     monkeypatch.setattr(w, "_make_chunk_fn", fake_make)
     w._chunk_fn_cache.clear()
@@ -165,14 +164,13 @@ def test_pallas_build_failure_raises(monkeypatch):
     calls = []
 
     def fake_make(B, W, SW, K, D, NB, jax_step, pallas_mode="off",
-                  jax_step_rows=None, compact=0, packed=False):
+                  jax_step_rows=None, packed=False):
         calls.append(pallas_mode)
         if pallas_mode == "on":
             raise RuntimeError("Mosaic lowering rejected kernel")
         return real_make(B, W, SW, K, D, NB, jax_step,
                          pallas_mode=pallas_mode,
-                         jax_step_rows=jax_step_rows,
-                         compact=compact, packed=packed)
+                         jax_step_rows=jax_step_rows, packed=packed)
 
     monkeypatch.setattr(w, "_make_chunk_fn", fake_make)
     w._chunk_fn_cache.clear()

@@ -73,19 +73,20 @@ def test_headline_bench_cpu_floor():
     CPU floor (VERDICT r3 'weak' #3: BENCH_r0N had no regression
     guard, so a silent 2x CPU-path regression would ship).  Measured
     under THIS suite's 8-virtual-device CPU split: ~76k ops/s with
-    round-4 candidate compaction, ~36k without (the split costs ~3x
-    vs the single-device 224k/77k bench.py sees — intra-op thread
-    pools shrink 8x).  The 50k floor both catches a generic 2x
-    regression AND fails if the compaction win is ever silently
-    lost.  Adaptive best-of-≤4 with early exit to damp CI machine
-    noise (~±20%)."""
+    round-4 candidate compaction, ~36k with the round's argsort over
+    the full tile (the split costs ~3x vs the single-device 224k/77k
+    bench.py sees — intra-op thread pools shrink 8x).  The chain
+    round now keeps its children with B masked-min passes over the
+    full tile, no sort; the 50k floor catches a generic 2x regression
+    AND a return of a per-round sort of the whole tile.  Adaptive
+    best-of-≤4 with early exit to damp CI machine noise (~±20%)."""
     from perf_utils import calibrated_floor
 
     floor = calibrated_floor(50_000)
     rate = _timed_wgl_rate(100_000, reps=4, floor=floor)
     assert rate > floor, (
         f"headline bench path regressed: {rate:,.0f} ops/s "
-        f"(floor {floor:,.0f} — did candidate compaction break?)"
+        f"(floor {floor:,.0f} — is the chain round sorting again?)"
     )
 
 
