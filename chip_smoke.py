@@ -28,9 +28,9 @@ mesh, one frontier-sharded search, and both again on one device.
 Each phase prints one `phase <name> {json}` line: verdicts, the engine
 that decided, the Pallas mode that ran, cold and warm wall seconds, and
 the fallback counters.  A phase fails on a raise, a verdict unequal to
-its reference, a non-zero `wgl.plan.fallback` or `wgl.degrade.*`
-counter, an engine named `...-degraded` / `...-nobackend`, or a witness
-that ran with Pallas off on the TPU.  The last line is the contract:
+its reference, a non-zero `wgl.degrade.*` counter, an engine named
+`...-degraded` / `...-nobackend`, or a witness that ran with Pallas
+off on the TPU.  The last line is the contract:
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}`.
 Times here are smoke timings, not benchmark numbers.
 
@@ -57,10 +57,10 @@ def _counters() -> dict:
 
 
 def _fallbacks(counters: dict) -> dict:
-    """The counters that must stay zero: plan-executor fallbacks and
-    every degradation-ladder step."""
+    """The counters that must stay zero: every degradation-ladder
+    step."""
     return {k: v for k, v in sorted(counters.items())
-            if k == "wgl.plan.fallback" or k.startswith("wgl.degrade.")}
+            if k.startswith("wgl.degrade.")}
 
 
 def _pallas_modes(counters: dict) -> dict:

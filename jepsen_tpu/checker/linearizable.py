@@ -156,30 +156,13 @@ class Linearizable(Checker):
                                           time_limit_s=remaining)
             return self._render(res, packed, engine, model, pm, opts=opts)
 
-        # Compiled-plan route for the auto device paths: the same
-        # ladder (_device_first) as a plan-executor pass, fronted by
-        # the persistent plan memo when a cache directory is
-        # configured.  Explicitly named engines above never route —
-        # they are exercised as asked.
-        from ..plan import enabled as _plan_enabled
+        # Compiled-plan route for the auto device paths: _device_first
+        # as a plan-executor pass, fronted by the persistent plan memo
+        # when a cache directory is configured.  Explicitly named
+        # engines above never route — they are exercised as asked.
+        from ..plan.compiler import run_single
 
-        if _plan_enabled():
-            try:
-                from ..plan.compiler import run_single
-
-                return run_single(self, packed, pm, model, algorithm,
-                                  test, opts)
-            except Exception:  # noqa: BLE001 — legacy ladder is the net
-                import logging
-
-                telemetry.count("wgl.plan.fallback")
-                logging.getLogger(__name__).warning(
-                    "plan executor failed; using the legacy ladder",
-                    exc_info=True,
-                )
-
-        return self._device_first(packed, pm, model, algorithm, test,
-                                  opts)
+        return run_single(self, packed, pm, model, algorithm, test, opts)
 
     def _device_first(self, packed, pm, model, algorithm: str,
                       test: dict, opts: dict) -> dict:

@@ -487,15 +487,13 @@ class Scheduler:
         except Exception:  # noqa: BLE001 — STATS must never fail on
             # an advisory summary
             out["roofline"] = None
-        # Plan-layer health: routing flag, persistent cache hit rates,
-        # and which passes the cost model covers — the /fleet plan
-        # panel renders this block.
-        from .. import plan as _plan
+        # Plan-layer health: persistent cache hit rates and which
+        # passes the cost model covers — the /fleet plan panel renders
+        # this block.
         from ..plan import cache as plan_cache
         from ..plan import costmodel
 
         out["plan"] = {
-            "enabled": _plan.enabled(),
             "cache": plan_cache.stats(),
             "costmodel": costmodel.model_info(),
         }
@@ -624,6 +622,7 @@ class Scheduler:
         from ..checker.linearizable import Linearizable
         from ..ops import degrade
         from ..parallel.independent import IndependentChecker
+        from ..plan.compiler import run_packs
 
         head = group[0]
         model = self.model_for(head.model_spec)
@@ -654,7 +653,7 @@ class Scheduler:
                         chk._check_linearizable(test, merged_subs, {})
                     )
                 if merged_packs:
-                    out.update(_settle_packs(
+                    out.update(run_packs(
                         merged_packs, model, lin,
                         deadline=None if budget is None
                         else time.monotonic() + budget,
@@ -773,110 +772,6 @@ class Scheduler:
                 "key-results": krs,
                 "checkerd": meta,
             }
-
-
-def _settle_packs(
-    packs: dict[Any, Any], model: Any, lin: Any,
-    deadline: Optional[float],
-) -> dict[Any, dict]:
-    """The settling ladder for wire-packed submissions, which skip
-    re-encoding: cohort-wide stream witness, then per-pack settle memo,
-    refutation screen, and exact CPU engine.  (No batched-BFS tier:
-    packed submissions are the bulk-transport path and the stream +
-    screen + memo trio decides the common families; survivors go
-    straight to the exact engine, still sound.)"""
-    from ..checker.refute import check_refute
-    from ..ops.wgl_stream import check_wgl_witness_stream
-    from ..parallel import independent as pind
-
-    # Compiled-plan route: the same stream / memo / decide-mode screen
-    # / exact pipeline as a pass DAG (jepsen_tpu/plan/), with the
-    # daemon's persistent plan memo in front when --plan-cache is set.
-    from ..plan import enabled as _plan_enabled
-
-    if _plan_enabled():
-        try:
-            from ..plan.compiler import run_packs
-
-            return run_packs(packs, model, lin, deadline)
-        except Exception:  # noqa: BLE001 — legacy ladder is the net
-            telemetry.count("wgl.plan.fallback")
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "plan executor failed; using the legacy packs ladder",
-                exc_info=True,
-            )
-
-    pm = model.packed()
-
-    def left() -> Optional[float]:
-        if deadline is None:
-            return None
-        return max(1.0, deadline - time.monotonic())
-
-    out: dict[Any, dict] = {}
-    live = []
-    for k, p in packs.items():
-        if p.n == 0:
-            out[k] = {"valid": True, "algorithm": "empty"}
-        else:
-            live.append(k)
-    if not live:
-        return out
-    if "stream" in overload.dropped_passes():
-        # Brownout level 1+: the witness beam is the first optional
-        # tier to go — it only ever proves keys early, so skipping it
-        # routes work to the sound exact tiers below.
-        telemetry.count("checkerd.overload.brownout-skip-stream")
-        stream_v = [None] * len(live)
-    else:
-        try:
-            stream_v = check_wgl_witness_stream(
-                [packs[k] for k in live], pm, time_limit_s=left(),
-            )
-        except Exception:  # noqa: BLE001 — sound fallback below
-            stream_v = [None] * len(live)
-    rest = []
-    for k, v in zip(live, stream_v):
-        if v is True:
-            out[k] = {
-                "valid": True,
-                "algorithm": "wgl-tpu-stream",
-                "configs-explored": int(packs[k].n_ok),
-            }
-        else:
-            rest.append(k)
-    for k in rest:
-        p = packs[k]
-        digest = pind._settle_digest(p, pm)
-        hit = pind._memo_get(digest)
-        if hit is not None:
-            hit["memo-hit"] = True
-            out[k] = hit
-            continue
-        ref = None
-        try:
-            b = left()
-            ref = check_refute(
-                p, pm, time_limit_s=30.0 if b is None else min(b, 30.0),
-            )
-        except Exception:  # noqa: BLE001 — screens may not veto
-            ref = None
-        if ref is not None:
-            res, engine = ref, "refute-screen"
-        else:
-            res, engine = lin._cpu_exact(p, pm, "auto", time_limit_s=left())
-        r: dict[str, Any] = {
-            "valid": res.valid,
-            "algorithm": engine,
-            "configs-explored": int(res.configs_explored),
-        }
-        if res.valid == "unknown" and res.reason:
-            r["reason"] = res.reason
-        pind._memo_put(digest, r)
-        out[k] = r
-    return out
 
 
 def _device_info() -> dict:

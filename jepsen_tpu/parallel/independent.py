@@ -20,10 +20,9 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Any, Callable, Iterable, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 from .. import telemetry
-from ..telemetry import profile
 from ..checker.core import Checker, check_safe, merge_valid
 from ..checker.linearizable import Linearizable
 from ..history.core import History, Op
@@ -369,7 +368,6 @@ class IndependentChecker(Checker):
             return dict(zip(keys, rs))
 
         from ..history.packed import pack_history
-        from .mesh import checker_mesh
 
         all_packs = {}
         unpackable = []
@@ -389,351 +387,16 @@ class IndependentChecker(Checker):
                     continue
                 all_packs[k] = p
 
-        # Compiled-plan route (jepsen_tpu/plan/): the same ladder —
-        # online consume, long-key split, stream witness, settle
-        # pipeline — expressed as a pass DAG and run by the plan
-        # executor, with cost-model knobs and (opt-in) persistent
-        # memoization.  JEPSEN_PLAN=0 keeps the hand-wired ladder
-        # below, which the parity suites diff against.
-        from ..plan import enabled as _plan_enabled
+        # The plan executor (jepsen_tpu/plan/) runs the ladder —
+        # online consume, long-key split, stream witness, screens,
+        # batched BFS, exact settle — as a pass DAG, with cost-model
+        # knobs and (opt-in) persistent memoization.
+        from ..plan.compiler import run_cohort
 
-        if _plan_enabled():
-            try:
-                from ..plan.compiler import run_cohort
-
-                return run_cohort(
-                    self, test, subs,
-                    [k for k in keys if k in all_packs],
-                    unpackable, all_packs, model, pm, lin, opts,
-                )
-            except Exception:  # noqa: BLE001 — legacy ladder is the net
-                telemetry.count("wgl.plan.fallback")
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "plan executor failed; using the legacy ladder",
-                    exc_info=True,
-                )
-
-        results_unpack: dict[Any, dict] = {}
-        if unpackable:
-            rs = bounded_pmap(
-                lambda k: check_safe(
-                    lin, test, subs[k], {**opts, "history_key": k}
-                ),
-                unpackable,
-                bound=self.bound,
-            )
-            results_unpack = dict(zip(unpackable, rs))
-            keys = [k for k in keys if k in all_packs]
-            if not keys:
-                return results_unpack
-        # Online verdicts first: a streaming session (jepsen_tpu/
-        # streaming/) may have proven keys while the run was still
-        # generating ops.  A verdict is consumed only when the key's
-        # re-packed digest equals the one recorded at proof time, so a
-        # key that changed after its proof settles from scratch here.
-        results_online: dict[Any, dict] = {}
-        sess = (test or {}).get("streaming-session")
-        if self.streaming and sess is not None:
-            for k in keys:
-                d = _online_digest(sess, pm, subs[k])
-                r = sess.consume(k, d) if d is not None else None
-                if r is not None:
-                    results_online[k] = r
-            if results_online:
-                keys = [k for k in keys if k not in results_online]
-                if telemetry.enabled():
-                    telemetry.count("wgl.settle.online-proven",
-                                    len(results_online))
-            if not keys:
-                return {**results_unpack, **results_online}
-        # Long keys skip the batched kernel entirely: its compile/pad
-        # cost scales with the LONGEST key, and the single-history
-        # witness-first path (check_wgl_device) is built for length.
-        long_keys = [k for k in keys if all_packs[k].n > 2000]
-        keys = [k for k in keys if all_packs[k].n <= 2000]
-        results_long: dict[Any, dict] = {}
-        if long_keys:
-            long_chk = Linearizable(
-                model, "wgl-tpu",
-                beam=lin.beam, max_beam=lin.max_beam,
-                time_limit_s=lin.time_limit_s,
-                max_configs=lin.max_configs,
-            )
-            rs = bounded_pmap(
-                lambda k: check_safe(
-                    long_chk, test, subs[k], {**opts, "history_key": k}
-                ),
-                long_keys,
-                bound=self.bound,
-            )
-            results_long = dict(zip(long_keys, rs))
-            if not keys:
-                return {**results_unpack, **results_online,
-                        **results_long}
-
-        # Stream-witness first (ops/wgl_stream.py): ALL keys ride one
-        # concatenated barrier stream through the witness engine —
-        # measured ~20x the batched-BFS rate on the 200x100 shape
-        # (VERDICT r4 'weak' #3).  Keys it proves are done; the rest
-        # (rare) fall through to the exact engines below.
-        from ..ops.wgl_stream import check_wgl_witness_stream
-
-        # One budget for the whole tier ladder: the stream's elapsed
-        # time is deducted before the batched search and the per-key
-        # CPU settles, so the caller's time_limit_s bounds the whole
-        # check, not each tier separately.
-        import time as _time
-
-        t_tiers = _time.monotonic()
-
-        def budget_left():
-            if lin.time_limit_s is None:
-                return None
-            return max(1.0, lin.time_limit_s
-                       - (_time.monotonic() - t_tiers))
-
-        results_stream: dict[Any, dict] = {}
-        # Resource errors degrade inside the stream tier; anything
-        # else is a bug and propagates.
-        stream_v = check_wgl_witness_stream(
-            [all_packs[k] for k in keys], pm,
-            time_limit_s=lin.time_limit_s,
+        return run_cohort(
+            self, test, subs, [k for k in keys if k in all_packs],
+            unpackable, all_packs, model, pm, lin, opts,
         )
-        for k, v in zip(keys, stream_v):
-            if v is True:
-                results_stream[k] = {
-                    "valid": True,
-                    "algorithm": "wgl-tpu-stream",
-                    "configs-explored": int(all_packs[k].n_ok),
-                }
-        keys = [k for k, v in zip(keys, stream_v) if v is not True]
-        if telemetry.enabled():
-            telemetry.count("wgl.settle.stream-proven",
-                            len(results_stream))
-        if not keys:
-            return {**results_unpack, **results_online, **results_long,
-                    **results_stream}
-
-        results: dict[Any, dict] = {
-            **results_unpack, **results_online, **results_long,
-            **results_stream,
-        }
-        results.update(self._settle_cohort(
-            keys, all_packs, subs, model, pm, lin, test, opts,
-            budget_left, checker_mesh(test),
-        ))
-        return results
-
-    #: Detail budget for keys the batched kernel already proved invalid
-    #: EXACTLY: the CPU pass is reporting-only there (the verdict
-    #: stands), so it gets a small slice, not the whole tier budget.
-    REFUTED_DETAIL_BUDGET_S = 10.0
-
-    def _settle_cohort(
-        self, cohort_keys, all_packs, subs, model, pm, lin, test, opts,
-        budget_left, mesh,
-    ) -> dict[Any, dict]:
-        """Decides the cohort the stream witness left unproven, under
-        the shared tier budget.  The pipeline, cheapest tier first:
-
-          1. **memo** — identical subhistories (packed digest,
-             src_index excluded) replay a prior decisive verdict; one
-             representative per digest runs the rest of the pipeline
-             and fans its sanitized verdict out.
-          2. **refutation screens** (checker/refute.py) — host numpy,
-             O(n log n), exact when they fire.  They classify the
-             planted-violation/bad-read families in milliseconds, so
-             those keys never enter the batched BFS (proving `invalid`
-             there means EXHAUSTING the per-key search — the expensive
-             direction).
-          3. **batched BFS** (ops/wgl_batched.py) — screen survivors
-             only, vmapped over the mesh; True is proven, False is an
-             exact device refutation.
-          4. **parallel CPU settle** — the remainder (screen-refuted
-             keys for certificate detail, device-refuted keys for a
-             small-budget detail pass, unknowns for the exact engine)
-             under bounded_pmap, every slice carved from the same
-             tier budget."""
-        # One cost record for the whole settle pipeline; the
-        # chained span hook folds the batched children's
-        # compile/execute time into this record too.
-        with profile.capture(
-            "settle", keys=len(cohort_keys),
-            ops=int(sum(all_packs[k].n for k in cohort_keys)),
-        ) as _ps:
-            import logging
-
-            from ..checker.refute import check_refute
-            from ..ops.wgl_batched import check_wgl_batched
-
-            log = logging.getLogger(__name__)
-            groups: "OrderedDict[str, list]" = OrderedDict()
-            for k in cohort_keys:
-                d = _settle_digest(all_packs[k], pm)
-                groups.setdefault(d, []).append(k)
-
-            group_result: dict[str, dict] = {}
-            reps: list[str] = []
-            for d in groups:
-                hit = _memo_get(d)
-                if hit is not None:
-                    group_result[d] = hit
-                else:
-                    reps.append(d)
-            n_memo = sum(len(groups[d]) for d in group_result)
-
-            # Screen classifier: which representatives are provably invalid
-            # without any search.  Sound-when-fires; None = no opinion.
-            def screen_one(d: str):
-                b = budget_left()
-                try:
-                    return check_refute(
-                        all_packs[groups[d][0]], pm,
-                        time_limit_s=30.0 if b is None else min(b, 30.0),
-                    )
-                except Exception:  # noqa: BLE001 — a screen bug must not
-                    log.warning("refutation screen failed for key %r",
-                                groups[d][0], exc_info=True)
-                    return None  # change a verdict; the search tiers decide
-
-            screened = dict(zip(reps, bounded_pmap(screen_one, reps,
-                                                   bound=self.bound)))
-            refuted_reps = [d for d in reps if screened[d] is not None]
-            survivors = [d for d in reps if screened[d] is None]
-
-            # Batched frontier BFS over the screen survivors.  Start the
-            # beam SMALL: the overflow-retry ladder re-batches only the
-            # keys that overflowed, so typical short per-key histories
-            # settle in the cheap narrow passes and only the rare wide key
-            # climbs.  Measured (200 keys x 100 ops, 8-dev CPU mesh,
-            # warm): start 32 = 1.8 s vs start 256 = 16.3 s — the
-            # per-step frontier work scales with the start width for
-            # EVERY key, paid even by keys the narrowest pass would
-            # settle.  32 is the kernel's smallest beam bucket
-            # (check_wgl_batched's _bucket lo=32; anything lower rounds
-            # up to it).  Worst case (all keys climb to max) the
-            # geometric ladder costs ~2x the final pass — bounded, and
-            # far rarer than the all-keys-small common case.
-            device_verdict: dict[str, Any] = {d: None for d in reps}
-            device_explored: dict[str, int] = {d: 0 for d in reps}
-            n_batched_proven = 0
-            if survivors:
-                batch = check_wgl_batched(
-                    [all_packs[groups[d][0]] for d in survivors],
-                    pm,
-                    beam=min(lin.beam, 32),
-                    max_beam=max(lin.max_beam, lin.beam),
-                    mesh=mesh,
-                    time_limit_s=budget_left(),
-                )
-                for i, d in enumerate(survivors):
-                    device_verdict[d] = batch.valid[i]
-                    device_explored[d] = int(batch.explored[i])
-                    if batch.valid[i] is True:
-                        group_result[d] = {
-                            "valid": True,
-                            "algorithm": "wgl-tpu-batched",
-                            "configs-explored": int(batch.explored[i]),
-                        }
-                        _memo_put(d, group_result[d])
-                        n_batched_proven += 1
-
-            # Parallel CPU settle of everything still without a result:
-            # screen-refuted reps (the "settle" algorithm re-fires the
-            # cheap screen and renders the certificate), device-refuted
-            # reps (small detail slice; the exact device verdict stands if
-            # the slice expires), and device unknowns (exact engine).
-            todo = [d for d in reps if d not in group_result]
-
-            def settle_one(d: str) -> dict:
-                k = groups[d][0]
-                dv = device_verdict[d]
-                budget = budget_left()
-                if dv is False:
-                    budget = (self.REFUTED_DETAIL_BUDGET_S if budget is None
-                              else min(budget, self.REFUTED_DETAIL_BUDGET_S))
-                single = Linearizable(
-                    model,
-                    "settle",
-                    time_limit_s=budget,
-                    max_configs=lin.max_configs,
-                )
-                r = check_safe(single, test, subs[k],
-                               {**opts, "history_key": k})
-                if dv is not None:
-                    r["device-verdict"] = dv
-                if dv is False:
-                    if r.get("valid") == "unknown":
-                        # The detail slice expired; the device refutation
-                        # is exact (search exhausted without overflow) and
-                        # settles the verdict on its own.
-                        r = {
-                            "valid": False,
-                            "algorithm": "wgl-tpu-batched",
-                            "configs-explored": device_explored[d],
-                            "device-verdict": False,
-                        }
-                    elif r.get("valid") is True:
-                        # Exact engines disagreeing is a checker bug, not a
-                        # history property; surface it loudly and keep the
-                        # CPU verdict (parity with per-key exact checking).
-                        log.error(
-                            "device/CPU verdict mismatch on key %r: batched"
-                            " kernel proved invalid, exact engine proved "
-                            "valid — keeping the CPU verdict", k,
-                        )
-                return r
-
-            n_screen = n_device_refuted = n_cpu = 0
-            screen_fired = set(refuted_reps)
-            for d, r in zip(todo, bounded_pmap(settle_one, todo,
-                                               bound=self.bound)):
-                group_result[d] = r
-                _memo_put(d, r)
-                if device_verdict[d] is False:
-                    n_device_refuted += 1
-                elif d in screen_fired:
-                    n_screen += 1
-                else:
-                    n_cpu += 1
-
-            # Fan every group's verdict out: the representative carries the
-            # full result (positional certificate fields cite ITS slice of
-            # the history); other members share the sanitized verdict.
-            settled: dict[Any, dict] = {}
-            live = set(reps)
-            for d, members in groups.items():
-                r = group_result.get(d)
-                if r is None:  # defensive: unreachable
-                    continue
-                if d in live:
-                    settled[members[0]] = r
-                    extra = members[1:]
-                    n_memo += len(extra)
-                else:
-                    extra = members  # cross-call memo hit: all share
-                for k2 in extra:
-                    shared = _sanitize_settle(r)
-                    shared["memo-hit"] = True
-                    settled[k2] = shared
-            if telemetry.enabled():
-                telemetry.count("wgl.settle.screen-refuted", n_screen)
-                telemetry.count("wgl.settle.batched-proven",
-                                n_batched_proven)
-                telemetry.count("wgl.settle.batched-refuted",
-                                n_device_refuted)
-                telemetry.count("wgl.settle.cpu-settled", n_cpu)
-                telemetry.count("wgl.settle.memo-hit", n_memo)
-            _ps.outcome = {
-                "screen-refuted": n_screen,
-                "batched-proven": n_batched_proven,
-                "batched-refuted": n_device_refuted,
-                "cpu-settled": n_cpu,
-                "memo-hit": n_memo,
-            }
-            return settled
 
 
 def independent_checker(base: Checker, **kw: Any) -> IndependentChecker:

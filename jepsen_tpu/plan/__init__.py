@@ -1,10 +1,10 @@
 """Checking-plan IR: compile histories to pass DAGs, execute them once.
 
-The checker tier zoo (witness / stream / frontier / batched / BFS /
-settle / exact-CPU, plus the elle SCC path) grew point-to-point: every
-caller — `Linearizable`, `IndependentChecker._settle_cohort`, the
-checkerd scheduler, the streaming pipeline — wired the degradation
-ladder by hand and re-taught it its special cases.  This package is the
+The checker tiers (witness / stream / frontier / batched / BFS /
+settle / exact-CPU, plus the elle SCC path) are pass families, and the
+callers — `Linearizable`, `IndependentChecker`, the checkerd scheduler
+— reach the engines only through a compiled plan, so the ladder's order
+and its special cases live in one place.  This package is the
 compile-then-execute split (TVM's architecture, PAPERS.md) applied to
 checking:
 
@@ -26,8 +26,6 @@ checking:
                      JAX's on-disk compilation cache, so fresh
                      processes and restarted daemons skip recompilation
 
-Routing is behind `JEPSEN_PLAN` (default on); `JEPSEN_PLAN=0` keeps
-the legacy point-to-point ladder, which the parity suites diff against.
 The persistent caches activate only when `JEPSEN_PLAN_CACHE=<dir>` (or
 `checkerd --plan-cache`) names a directory — in-memory behavior is
 byte-identical either way.
@@ -35,18 +33,9 @@ byte-identical either way.
 
 from __future__ import annotations
 
-import os
-
-#: Routing flag: "0"/"false"/"off" disables the plan path.
-PLAN_ENV = "JEPSEN_PLAN"
 #: Persistent cache directory (plan memo + XLA compile cache); unset
 #: means no on-disk state.
 CACHE_ENV = "JEPSEN_PLAN_CACHE"
-
-
-def enabled() -> bool:
-    """Whether checking routes through the plan compiler/executor."""
-    return os.environ.get(PLAN_ENV, "1").lower() not in ("0", "false", "off")
 
 
 from .ir import (  # noqa: E402
@@ -60,11 +49,9 @@ from .ir import (  # noqa: E402
 
 __all__ = [
     "CACHE_ENV",
-    "PLAN_ENV",
     "Plan",
     "PassFamily",
     "PassNode",
-    "enabled",
     "family",
     "known_families",
     "register_family",

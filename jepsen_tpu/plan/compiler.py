@@ -1,23 +1,18 @@
 """The plan compiler: packed work + model spec + budget -> Plan.
 
-Three entry points mirror the three call sites that used to wire the
-tier ladder by hand:
+Three entry points, one per caller:
 
-* `run_cohort`  — IndependentChecker's per-key cohort (subsumes the
-  online-consume / long-key split / stream witness / `_settle_cohort`
-  pipeline)
-* `run_packs`   — checkerd's wire-packed submissions (subsumes
-  `_settle_packs`: stream, memo, decide-mode screen, exact CPU —
-  no batched tier)
+* `run_cohort`  — IndependentChecker's per-key cohort (online consume,
+  long-key split, stream witness, screens, batched BFS, exact settle)
+* `run_packs`   — checkerd's wire-packed submissions (stream, memo,
+  decide-mode screen, exact CPU — no batched tier)
 * `run_single`  — one Linearizable history on the auto device paths
 
 Each compiles a Plan whose knobs come from the cost model
-(plan/costmodel.py) — the hand heuristics when untrained, in which
-case every knob equals the legacy formula and the compiled plan is
-behavior-identical to the hand-wired ladder — and executes it through
-plan/executor.py.  The persistent-memo node is inserted only when a
-cache directory is configured (cache.py), so default runs have no
-on-disk state.
+(plan/costmodel.py) — the hand heuristics when untrained — and
+executes it through plan/executor.py.  The persistent-memo node is
+inserted only when a cache directory is configured (cache.py), so
+default runs have no on-disk state.
 """
 
 from __future__ import annotations
@@ -83,9 +78,9 @@ def compile_cohort_plan(
     n_keys: int, n_ops: int, *,
     has_unpackable: bool,
 ) -> tuple[Plan, str]:
-    """-> (plan, entry-node-id for packable keys).  Node order is the
-    legacy ladder's; the cost model only turns knobs (and may drop the
-    stream tier when trained data says it loses)."""
+    """-> (plan, entry-node-id for packable keys).  Node order is
+    fixed, cheapest tier first; the cost model only turns knobs (and
+    may drop the stream tier when trained data says it loses)."""
     sess = (test or {}).get("streaming-session") \
         if getattr(checker, "streaming", True) else None
     cache_on = plan_cache.cache_dir() is not None
@@ -172,9 +167,8 @@ def run_cohort(
     unpackable: list, packs: dict, model: Any, pm: Any, lin: Any,
     opts: dict,
 ) -> dict:
-    """Compiles and executes the cohort plan; drop-in for everything
-    after the packing partition in
-    IndependentChecker._check_linearizable."""
+    """Compiles and executes the cohort plan: everything after the
+    packing partition in IndependentChecker._check_linearizable."""
     from ..parallel.mesh import checker_mesh
 
     n_ops = int(sum(packs[k].n for k in packable))
@@ -187,7 +181,7 @@ def run_cohort(
     ctx = ExecContext(
         test=test, subs=subs, packs=packs, model=model, pm=pm, lin=lin,
         opts=opts, bound=checker.bound, mesh=checker_mesh(test),
-        checker=checker, mode="cohort",
+        mode="cohort",
         identity=_identity(lin, pm, "cohort"),
     )
     seeds: dict = {}
@@ -205,6 +199,11 @@ def run_cohort(
 
 def compile_packs_plan(lin: Any, pm: Any, n_keys: int,
                        n_ops: int) -> tuple[Plan, str]:
+    """-> (plan, entry-node-id): stream witness, decide-mode screen,
+    exact CPU engine.  No batched-BFS tier: packed submissions are the
+    bulk-transport path and the stream + screen + memo trio decides the
+    common families; survivors go straight to the exact engine, still
+    sound."""
     cache_on = plan_cache.cache_dir() is not None
     stream_knobs, s_src = costmodel.choose_stream_knobs(n_keys, n_ops)
     _knob_counter(s_src)
@@ -242,7 +241,8 @@ def compile_packs_plan(lin: Any, pm: Any, n_keys: int,
 
 def run_packs(packs: dict, model: Any, lin: Any,
               deadline: Optional[float]) -> dict:
-    """Drop-in for checkerd's _settle_packs."""
+    """Decides checkerd's wire-packed submissions: empty packs are
+    valid outright, the rest run the packs plan under `deadline`."""
     pm = model.packed()
     out: dict = {}
     live = []
@@ -278,7 +278,7 @@ def run_single(lin: Any, packed: Any, pm: Any, model: Any,
     """One history through the executor: a persistent-memo probe (when
     a cache dir is configured) in front of the device-first ladder.
     With no cache the plan is the single device-ladder node, whose
-    runner IS the legacy ladder."""
+    runner is `Linearizable._device_first`."""
     cache_on = plan_cache.cache_dir() is not None
     nodes: list[PassNode] = []
     feats = {"ops": int(packed.n), "ok": int(packed.n_ok)}

@@ -4,20 +4,18 @@
 nodes (the IR guarantees edges point forward): each node's pass family
 runner decides keys, routes the rest along the node's typed edges, and
 the sweep carries work queues node to node.  The contiguous tail of
-`group=True` nodes is the **digest-dedup scope** — the settle-memo
-mechanic of `IndependentChecker._settle_cohort` hoisted into the
-executor: on entry, keys collapse to one representative per packed
-digest (memo hits — in-memory settle memo first, then the persistent
-plan memo — skip the scope entirely); on exit, each representative's
-verdict fans out to its group, sanitized of positional certificates.
+`group=True` nodes is the **digest-dedup scope** (the settle memo):
+on entry, keys collapse to one representative per packed digest (memo
+hits — in-memory settle memo first, then the persistent plan memo —
+skip the scope entirely); on exit, each representative's verdict fans
+out to its group, sanitized of positional certificates.
 
-The family runners call the *same* engine helpers the legacy ladder
-calls (`check_wgl_witness_stream`, `check_refute`, `check_wgl_batched`,
-the `"settle"`-algorithm Linearizable, `_memo_get`/`_memo_put`), emit
-the same `wgl.settle.*` counters, and wrap the group scope in the same
-`profile.capture("settle")` record — so `JEPSEN_PLAN=1` and `=0`
-produce identical verdicts, counters, and training records by
-construction.  Plan-level telemetry lands under `wgl.plan.*`.
+The family runners call the engine helpers (`check_wgl_witness_stream`,
+`check_refute`, `check_wgl_batched`, the `"settle"`-algorithm
+Linearizable, `_memo_get`/`_memo_put`), emit the `wgl.settle.*`
+counters, and wrap the cohort group scope in one
+`profile.capture("settle")` record, the cost model's training input.
+Plan-level telemetry lands under `wgl.plan.*`.
 """
 
 from __future__ import annotations
@@ -36,6 +34,11 @@ from .ir import PassFamily, PassNode, Plan, family, register_family
 
 log = logging.getLogger(__name__)
 
+#: Detail budget for keys the batched kernel already proved invalid
+#: EXACTLY: the CPU pass is reporting-only there (the verdict stands),
+#: so it gets a small slice, not the whole tier budget.
+REFUTED_DETAIL_BUDGET_S = 10.0
+
 
 @dataclasses.dataclass
 class ExecContext:
@@ -52,7 +55,6 @@ class ExecContext:
     opts: dict
     bound: Optional[int] = None
     mesh: Any = None
-    checker: Any = None
     #: "cohort" (IndependentChecker), "packs" (checkerd wire-packed),
     #: or "single" (one Linearizable history).
     mode: str = "cohort"
@@ -66,7 +68,7 @@ class ExecContext:
     _digests: dict = dataclasses.field(default_factory=dict)
     _t0: Optional[float] = None
 
-    # -- shared tier budget (the legacy t_tiers clock) ----------------------
+    # -- shared tier budget: one clock for every tier ----------------------
 
     def start_clock(self) -> None:
         if self._t0 is None:
@@ -108,13 +110,13 @@ class ExecContext:
 
 
 # ---------------------------------------------------------------------------
-# Family runners — each reuses the exact legacy engine call.
+# Family runners — each a thin wrapper over one engine call.
 # ---------------------------------------------------------------------------
 
 
 def _run_host_fallback(ctx: ExecContext, node: PassNode, keys: list):
     """Keys with no packed form: the single-key checker under
-    bounded_pmap, exactly the legacy unpackable path."""
+    bounded_pmap (it falls back to the host-model search itself)."""
     from ..checker.core import check_safe
     from ..utils import bounded_pmap
 
@@ -345,23 +347,20 @@ def _run_settle_exact(ctx: ExecContext, node: PassNode, keys: list):
     """The parallel CPU settle: screen-refuted keys re-derive their
     certificate, device-refuted keys get a small detail slice (the
     exact device verdict stands if it expires), unknowns go to the
-    exact engine — the legacy settle_one, verbatim."""
+    exact engine."""
     from ..checker.core import check_safe
     from ..checker.linearizable import Linearizable
     from ..utils import bounded_pmap
 
     lin, model = ctx.lin, ctx.model
-    detail_budget = getattr(
-        ctx.checker, "REFUTED_DETAIL_BUDGET_S", 10.0
-    )
 
     def settle_one(k):
         n = ctx.notes.get(k) or {}
         dv = n.get("device_verdict")
         budget = ctx.budget_left()
         if dv is False:
-            budget = (detail_budget if budget is None
-                      else min(budget, detail_budget))
+            budget = (REFUTED_DETAIL_BUDGET_S if budget is None
+                      else min(budget, REFUTED_DETAIL_BUDGET_S))
         single = Linearizable(
             model, "settle",
             time_limit_s=budget,
@@ -407,8 +406,8 @@ def _run_settle_exact(ctx: ExecContext, node: PassNode, keys: list):
 
 
 def _run_packs_exact(ctx: ExecContext, node: PassNode, keys: list):
-    """Exact CPU engine over wire-packed submissions (the checkerd
-    `_settle_packs` tail: no subs, no batched tier)."""
+    """Exact CPU engine over wire-packed submissions (checkerd: no
+    subs, no batched tier)."""
     decided = {}
     for k in keys:
         res, engine = ctx.lin._cpu_exact(
@@ -516,8 +515,8 @@ class _GroupState:
 
 def _enter_group(ctx: ExecContext, keys: list) -> _GroupState:
     """Digest-groups the keys and replays memoized verdicts: the
-    in-memory settle memo first (exactly the legacy ladder), then the
-    persistent plan memo (which also warms the in-memory one)."""
+    in-memory settle memo first, then the persistent plan memo (which
+    also warms the in-memory one)."""
     from ..parallel.independent import _memo_get, _memo_put
 
     gs = _GroupState()
@@ -635,11 +634,10 @@ def execute(plan: Plan, ctx: ExecContext,
 
 def _execute_group(ctx: ExecContext, grp: list, gkeys: list,
                    work: dict, route: Callable) -> dict:
-    # One cost record for the whole settle pipeline (cohort mode only —
-    # the legacy packs path records no settle-level profile either);
-    # the chained span hook folds the batched children's compile/
-    # execute time into this record, keeping the cost-model training
-    # set shape identical across JEPSEN_PLAN values.
+    # One cost record for the whole settle pipeline, in cohort mode
+    # only (packs submissions add no settle record); the chained span
+    # hook folds the batched children's compile/execute time into this
+    # record.
     cap = (
         profile.capture(
             "settle", keys=len(gkeys),

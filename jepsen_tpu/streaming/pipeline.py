@@ -349,15 +349,13 @@ class StreamingSession:
         digests = [_settle_digest(p, self.pm) for p in packs]
         self._stream_rows_hwm = max(self._stream_rows_hwm,
                                     sum(int(p.n) for p in packs))
-        kw: dict = {}
-        from ..plan import enabled as _plan_enabled
-        if _plan_enabled():
-            from ..plan import costmodel
-            knobs, _src = costmodel.choose_stream_knobs(
-                len(packs), sum(int(p.n) for p in packs))
-            kw["segment_keys"] = knobs["segment"]
-            kw["max_restarts"] = knobs["max_restarts"]
-        verdicts = check_wgl_witness_stream(packs, self.pm, **kw)
+        from ..plan import costmodel
+        knobs, _src = costmodel.choose_stream_knobs(
+            len(packs), sum(int(p.n) for p in packs))
+        verdicts = check_wgl_witness_stream(
+            packs, self.pm, segment_keys=knobs["segment"],
+            max_restarts=knobs["max_restarts"],
+        )
         for k, d, v in zip(keys, digests, verdicts):
             self._attempted[k] = d
             if v is True:
@@ -502,25 +500,21 @@ class StreamingSession:
         # concatenated independent keys; a chunk at exactly the
         # high-water mark sits on the bucket edge, where one extra
         # indeterminate row tips into the next power of two and pays a
-        # fresh XLA compile seconds before the verdict.  With planning
-        # on and a trained cost model whose roofline-annotated stream
-        # records cover the candidate shape buckets, the model picks
-        # the chunk rows instead (plan/costmodel.py
-        # choose_finalize_chunk_rows); out of support it falls back to
-        # the same halving formula.
+        # fresh XLA compile seconds before the verdict.  With a trained
+        # cost model whose roofline-annotated stream records cover the
+        # candidate shape buckets, the model picks the chunk rows
+        # instead (plan/costmodel.py choose_finalize_chunk_rows); out of
+        # support it falls back to the same halving formula.
+        from ..plan import costmodel
+
         total_rows = sum(p.n for p in packs)
-        from ..plan import enabled as _plan_enabled
-        if _plan_enabled():
-            from ..plan import costmodel
-            cap, cap_src = costmodel.choose_finalize_chunk_rows(
-                len(rest), total_rows, self._stream_rows_hwm
-            )
-            if cap_src == "model":
-                telemetry.count("wgl.plan.finalize-chunk-model")
-            else:
-                telemetry.count("wgl.plan.finalize-chunk-heuristic")
+        cap, cap_src = costmodel.choose_finalize_chunk_rows(
+            len(rest), total_rows, self._stream_rows_hwm
+        )
+        if cap_src == "model":
+            telemetry.count("wgl.plan.finalize-chunk-model")
         else:
-            cap = max(192, self._stream_rows_hwm // 2)
+            telemetry.count("wgl.plan.finalize-chunk-heuristic")
         i = 0
         while i < len(rest):
             j, rows = i, 0

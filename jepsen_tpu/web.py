@@ -598,8 +598,6 @@ class Handler(http.server.BaseHTTPRequestHandler):
                 f"<tr><td>{html.escape(str(k))}</td>"
                 f"<td>{html.escape(str(v))}</td></tr>"
                 for k, v in [
-                    ("plan executor", "on" if plan.get("enabled")
-                     else "off"),
                     ("cache dir", cache.get("dir") or "(not configured)"),
                     ("memo entries", memo.get("entries")),
                     ("memo hits", memo.get("hits")),

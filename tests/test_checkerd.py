@@ -315,11 +315,8 @@ def test_restarted_daemon_warm_starts_from_plan_cache(tmp_path):
     journals settled plan-node verdicts; a RESTARTED daemon (fresh
     Scheduler over the same directory) must serve the byte-identical
     resubmission from the journal, and a budget change must MISS."""
-    from jepsen_tpu import plan as _plan
     from jepsen_tpu.plan import cache as plan_cache
 
-    if not _plan.enabled():
-        pytest.skip("JEPSEN_PLAN disabled")
     h = _mixed_history()
 
     def one_round(run_id, time_limit_s=None):

@@ -1,5 +1,6 @@
 """The invalid-heavy jepsen.independent shape through the cohort
-settling ladder (parallel/independent.py._settle_cohort).
+settling ladder (the plan executor's cohort plan, plan/compiler.py
+run_cohort).
 
 The bar is DIFFERENTIAL: the fast path (stream witness -> memo ->
 refutation screens -> batched BFS -> parallel CPU settle) must produce

@@ -18,7 +18,7 @@ from jepsen_tpu.history.core import History
 from jepsen_tpu.models.registers import CASRegister, Register
 from jepsen_tpu.parallel.independent import KV, IndependentChecker
 from jepsen_tpu.plan import cache as plan_cache
-from jepsen_tpu.plan import costmodel, enabled
+from jepsen_tpu.plan import costmodel
 from jepsen_tpu.plan.compiler import _identity, compile_cohort_plan
 from jepsen_tpu.plan.ir import PassFamily, PassNode, Plan, known_families
 
@@ -179,7 +179,6 @@ def _history(read_back=2):
     return History(ops)
 
 
-@pytest.mark.skipif(not enabled(), reason="JEPSEN_PLAN disabled")
 def test_checker_hits_memo_only_on_identical_resubmission(
         tmp_path, monkeypatch):
     monkeypatch.setenv("JEPSEN_PLAN_CACHE", str(tmp_path))
@@ -298,3 +297,97 @@ def test_cohort_plan_mirrors_legacy_ladder_order():
 class _FakeChecker:
     streaming = True
     bound = None
+
+
+# ---------------------------------------------------------------------
+# Executor errors: an honest unknown, no second engine
+
+
+def _single_history():
+    """One register: write 1, read it back (linearizable)."""
+    ops = []
+    for f, v in (("write", 1), ("read", 1)):
+        i = len(ops)
+        ops.append({"index": i, "type": "invoke", "process": 0, "f": f,
+                    "value": None if f == "read" else v, "time": i})
+        ops.append({"index": i + 1, "type": "ok", "process": 0, "f": f,
+                    "value": v, "time": i + 1})
+    return History(ops)
+
+
+def _via_linearizable():
+    from jepsen_tpu.checker.core import check_safe
+
+    r = check_safe(_lin(), {"name": "t"}, _single_history(), {})
+    return r, [r]
+
+
+def _via_independent():
+    from jepsen_tpu.checker.core import check_safe
+
+    r = check_safe(IndependentChecker(_lin()), {"name": "t"},
+                   _history(), {})
+    return r, [r]
+
+
+def _via_checkerd_packed():
+    import threading
+
+    from jepsen_tpu.checkerd.client import CheckerdClient
+    from jepsen_tpu.checkerd.protocol import model_to_spec
+    from jepsen_tpu.checkerd.server import make_server
+    from jepsen_tpu.history.packed import pack_history
+
+    packed = pack_history(_single_history(), Register().packed().encode)
+    srv = make_server("127.0.0.1", 0, batch_window_s=0.0)
+    t = threading.Thread(target=srv.serve_forever, daemon=True)
+    t.start()
+    try:
+        with CheckerdClient(f"127.0.0.1:{srv.server_address[1]}") as c:
+            ticket = c.submit_packed(
+                "plan-error", model_to_spec(Register()), [packed])
+            r = c.wait(ticket, deadline_s=120)
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        srv.scheduler.stop()
+        t.join(timeout=5)
+    return r, r["key-results"]
+
+
+@pytest.mark.parametrize("route", [
+    _via_linearizable, _via_independent, _via_checkerd_packed,
+], ids=["linearizable", "independent", "checkerd-packed"])
+def test_executor_error_is_an_honest_unknown(route, monkeypatch):
+    """An exception out of the plan executor reaches the caller's own
+    handler as `unknown` with the error: no other engine decides the
+    check instead.  The compiler binds `execute` by name, so that is
+    the name to break."""
+    from jepsen_tpu.parallel import independent as pind
+    from jepsen_tpu.plan import compiler
+
+    def broken(*_a, **_kw):
+        raise RuntimeError("executor broke")
+
+    monkeypatch.setattr(compiler, "execute", broken)
+    pind.clear_settle_memo()
+    was_on = telemetry.enabled()
+    telemetry.enable(True)
+    telemetry.reset()
+    try:
+        r, key_results = route()
+        summ = telemetry.summary()
+    finally:
+        telemetry.reset()
+        telemetry.enable(was_on)
+    assert r["valid"] == "unknown", r
+    assert key_results
+    for kr in key_results:
+        assert kr["valid"] == "unknown", kr
+        assert "executor broke" in kr["error"], kr
+    ran = [s for s in summ["spans"]
+           if s == "wgl.witness" or s.startswith("wgl.stream")]
+    assert ran == [], ran
+    settled = {k: v for k, v in summ["counters"].items()
+               if k.startswith("wgl.settle.") and v}
+    assert settled == {}, settled

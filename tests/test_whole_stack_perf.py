@@ -148,8 +148,8 @@ def test_independent_mixed_throughput_floor():
     violation.  Pre-ladder (serial CPU settles, device-exhausting
     batched refutations) this took ~60 s a check (~330 ops/s); with
     the memo -> refutation-screen -> batched -> parallel-settle
-    pipeline (parallel/independent.py._settle_cohort) the cold check
-    is ~1-3 s.  The floor guards the ladder itself: the settle memo
+    pipeline (the cohort plan, plan/compiler.py run_cohort) the cold
+    check is ~1-3 s.  The floor guards the ladder itself: the settle memo
     is CLEARED before every rep, so each rep pays the real screens
     and searches, not a memo replay — the floor would survive a memo
     regression but not a ladder regression."""

@@ -4,18 +4,18 @@
 Four phases over one fixed mixed 60-key register history (48 valid
 keys, 12 that defeat the checker):
 
-  1. COLD  — a fresh process with `JEPSEN_PLAN=1` and
-     `JEPSEN_PLAN_CACHE` pointing at an empty directory checks the
-     history; must journal plan-memo entries and populate the XLA
-     compile cache.
+  1. COLD  — a fresh process with `JEPSEN_PLAN_CACHE` pointing at an
+     empty directory checks the history; must journal plan-memo
+     entries and populate the XLA compile cache.
   2. WARM  — a second fresh process over the same cache directory
      re-checks the identical history; must HIT the persistent plan
      memo, add no new XLA cache files (every kernel compile is
      served from disk), produce byte-identical per-key verdicts, and
      not be slower than the cold run.
-  3. PARITY — a fresh process with `JEPSEN_PLAN=0` (the hand-wired
-     legacy ladder) must produce the same per-key (valid, algorithm)
-     pairs as the cold plan run.
+  3. PARITY — the exact CPU engine (`Linearizable(Register(), "cpu")`,
+     the cold run's model) checks every key on its own; the cold plan
+     run's per-key `valid` must equal it, with the 12 planted keys
+     invalid.
   4. DAEMON — a checkerd daemon started with `--plan-cache`, fed one
      remote run, then killed and RESTARTED over the same directory:
      the resubmitted history must hit the journaled plan memo
@@ -74,8 +74,8 @@ def build_history():
 
 
 def worker(out_path: str) -> int:
-    """One fresh-process check of the fixed history; plan/cache config
-    comes from the environment (JEPSEN_PLAN / JEPSEN_PLAN_CACHE)."""
+    """One fresh-process check of the fixed history; the cache config
+    comes from the environment (JEPSEN_PLAN_CACHE)."""
     from jepsen_tpu import telemetry
     from jepsen_tpu.checker.linearizable import Linearizable
     from jepsen_tpu.models.registers import Register
@@ -108,17 +108,13 @@ def worker(out_path: str) -> int:
     return 0
 
 
-def run_worker(tag: str, tmp: str, *, plan: str,
-               cache: str | None) -> dict:
+def run_worker(tag: str, tmp: str, *, cache: str) -> dict:
     out = os.path.join(tmp, f"{tag}.json")
     env = dict(os.environ)
-    env["JEPSEN_PLAN"] = plan
-    env.pop("JEPSEN_PLAN_CACHE", None)
     # The compile cache lives where JAX_COMPILATION_CACHE_DIR says; a
     # fresh one per smoke so the cold run starts empty.
     env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp, "xla")
-    if cache is not None:
-        env["JEPSEN_PLAN_CACHE"] = cache
+    env["JEPSEN_PLAN_CACHE"] = cache
     rc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker", out],
         env=env, timeout=600,
@@ -140,14 +136,34 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def check_verdicts(tag: str, rep: dict) -> None:
-    for k, r in rep["results"].items():
+def check_keys(tag: str, results: dict) -> None:
+    """Every planted key invalid, every other key valid."""
+    for k, r in results.items():
         bad = (int(k[1:]) % BAD_EVERY) == BAD_EVERY - 1
         if r["valid"] is not (not bad):
             fail(f"{tag}: key {k} valid={r['valid']}, "
                  f"expected {not bad}")
+
+
+def check_verdicts(tag: str, rep: dict) -> None:
+    check_keys(tag, rep["results"])
     if rep["valid"] is not False:
         fail(f"{tag}: top-level valid={rep['valid']}, expected False")
+
+
+def exact_verdicts() -> dict:
+    """Per-key verdicts of the exact CPU engine, each key checked on
+    its own: the reference the plan run is held to."""
+    from jepsen_tpu.checker.core import check_safe
+    from jepsen_tpu.checker.linearizable import Linearizable
+    from jepsen_tpu.models.registers import Register
+    from jepsen_tpu.parallel.independent import subhistories
+
+    ref = Linearizable(Register(), "cpu")
+    return {
+        str(k): {"valid": check_safe(ref, {}, sub, {}).get("valid")}
+        for k, sub in subhistories(build_history()).items()
+    }
 
 
 def daemon_phase(tmp: str) -> dict:
@@ -209,7 +225,7 @@ def main() -> None:
     tmp = tempfile.mkdtemp(prefix="plan-smoke-")
     cache = os.path.join(tmp, "cache")
 
-    cold = run_worker("cold", tmp, plan="1", cache=cache)
+    cold = run_worker("cold", tmp, cache=cache)
     check_verdicts("cold", cold)
     memo = (cold["cache"].get("memo") or {})
     if not memo.get("puts"):
@@ -218,7 +234,7 @@ def main() -> None:
     if not xla_after_cold:
         fail("cold run populated no XLA compile-cache files")
 
-    warm = run_worker("warm", tmp, plan="1", cache=cache)
+    warm = run_worker("warm", tmp, cache=cache)
     check_verdicts("warm", warm)
     wmemo = (warm["cache"].get("memo") or {})
     if not wmemo.get("hits"):
@@ -235,22 +251,24 @@ def main() -> None:
         fail(f"warm run slower than cold: {warm['wall_s']}s vs "
              f"{cold['wall_s']}s")
 
-    legacy = run_worker("legacy", tmp, plan="0", cache=None)
-    check_verdicts("legacy", legacy)
+    exact = exact_verdicts()
+    check_keys("exact", exact)
+    odd = sorted(set(exact) ^ set(cold["results"]))
+    if odd:
+        fail(f"plan run and exact engine decided different keys: "
+             f"{odd[:4]}")
     mismatch = {
-        k for k in cold["results"]
-        if cold["results"][k] != legacy["results"].get(k)
+        k for k in exact
+        if cold["results"][k]["valid"] != exact[k]["valid"]
     }
     if mismatch:
-        examples = {k: (cold["results"][k], legacy["results"].get(k))
+        examples = {k: (cold["results"][k], exact[k])
                     for k in sorted(mismatch)[:4]}
-        fail(f"plan/legacy per-pass parity broke on "
+        fail(f"plan/exact per-key parity broke on "
              f"{len(mismatch)} keys: {examples}")
     if not any(k.startswith("wgl.plan.") for k in cold["counters"]):
         fail(f"cold run emitted no wgl.plan.* counters: "
              f"{cold['counters']}")
-    if any(k.startswith("wgl.plan.") for k in legacy["counters"]):
-        fail(f"legacy run emitted plan counters: {legacy['counters']}")
 
     stats = daemon_phase(tmp)
     plan_stats = stats.get("plan") or {}
@@ -261,7 +279,7 @@ def main() -> None:
     print(f"PASS: cold {cold['wall_s']}s -> warm {warm['wall_s']}s, "
           f"memo {memo.get('puts')} stored / {wmemo.get('hits')} hit, "
           f"xla files {xla_after_cold} (no new on warm), "
-          f"legacy parity on {len(cold['results'])} keys, "
+          f"exact-engine parity on {len(exact)} keys, "
           f"daemon warm-start hits={dmemo.get('hits')}")
 
 
